@@ -13,6 +13,7 @@ from repro.core.mlalgos import train_linreg, train_logreg, train_kmeans
 from repro.core.mlalgos.linreg import closed_form
 from repro.core.mlalgos.logreg import accuracy
 from repro.core import lut
+from repro.compile_cache import place_compile_cache
 
 
 def run():
@@ -50,4 +51,5 @@ def run():
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     run()

@@ -16,6 +16,7 @@ from benchmarks.common import time_fn, emit
 from repro.kernels import dispatch, ops, ref
 from repro.core import lut as lutm
 from repro.core import quantize as qz
+from repro.compile_cache import place_compile_cache
 
 
 def run():
@@ -97,4 +98,5 @@ def run():
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     run()

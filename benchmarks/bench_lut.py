@@ -10,6 +10,7 @@ import numpy as np
 
 from benchmarks.common import time_fn, emit
 from repro.core import lut
+from repro.compile_cache import place_compile_cache
 
 
 def run():
@@ -34,4 +35,5 @@ def run():
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     run()

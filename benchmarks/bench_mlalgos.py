@@ -16,6 +16,7 @@ from repro.core.mlalgos import (train_linreg, train_logreg, train_kmeans,
                                 train_dtree, train_svm,
                                 train_multinomial, LinReg)
 from repro.configs.pim_ml import CONFIG as C
+from repro.compile_cache import place_compile_cache
 
 
 def _one_step_timer(build_step, *args):
@@ -183,4 +184,5 @@ def run():
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     run()

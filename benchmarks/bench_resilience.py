@@ -48,6 +48,7 @@ from repro.core.mlalgos import make_linreg_step
 from repro.resilience import (FaultEvent, FaultPlan, RecoveryPolicy,
                               drive_fit, faults)
 from repro.distributed.merge_plan import MergePlan
+from repro.compile_cache import place_compile_cache
 
 VDPUS_FULL = (16, 64, 256)
 VDPUS_SMOKE = (4, 16)
@@ -196,6 +197,7 @@ def run(*, smoke: bool = False, out: str = "BENCH_resilience.json"):
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-size sweep (n_vdpus <= 16)")

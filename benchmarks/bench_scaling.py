@@ -109,6 +109,7 @@ from repro.core.mlalgos.multinomial import multinomial_accuracy
 from repro.distributed import compression as comp
 from repro.distributed.merge_plan import (MergePlan, SlowMo,
                                           AdaptiveCadence)
+from repro.compile_cache import place_compile_cache
 
 VDPUS_FULL = (1, 4, 16, 64, 256, 1024, 2048)
 VDPUS_SMOKE = (1, 4, 16)
@@ -754,6 +755,7 @@ def run(*, smoke: bool = False, out: str = "BENCH_scaling.json"):
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-size sweep (n_vdpus <= 16, small dataset)")

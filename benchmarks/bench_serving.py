@@ -54,6 +54,7 @@ from repro.core.mlalgos.linreg import LinReg
 from repro.core.mlalgos.multinomial import MultinomialLogReg
 from repro.core.mlalgos.svm import LinearSVM
 from repro.serving import MicroBatchQueue, PredictRunner
+from repro.compile_cache import place_compile_cache
 
 # the sweep axes (config promises = exactly these; bench_diff checks)
 WORKLOADS = ("linreg", "svm", "multinomial")
@@ -215,6 +216,7 @@ def run(*, smoke: bool = False, out: str = "BENCH_serving.json"):
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-size sweep (fewer requests / loads)")

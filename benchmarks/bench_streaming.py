@@ -50,6 +50,7 @@ from repro.core.mlalgos.linreg import LinReg
 from repro.core.mlalgos.logreg import LogReg
 from repro.core.mlalgos.svm import LinearSVM
 from repro.data import StreamingDataset
+from repro.compile_cache import place_compile_cache
 
 # the sweep axes (config promises = exactly these; bench_diff checks)
 DEPTHS_FULL = (0, 1, 2, 4)
@@ -198,6 +199,7 @@ def run(*, smoke: bool = False, out: str = "BENCH_streaming.json"):
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-size sweep (n_vdpus <= 16)")

@@ -14,6 +14,8 @@ EXPERIMENTS.md §Roofline.
 
 import argparse
 
+from repro.compile_cache import place_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -38,4 +40,5 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    place_compile_cache()
     main()

@@ -111,7 +111,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.resilience import faults as _faults
 
@@ -176,20 +176,34 @@ class PimGrid:
         ``(n_vdpus, rows_per_vdpu, ...)`` plus a 0/1 ``w`` mask marking
         real rows — local statistics must be weighted by ``w`` so padding
         never contaminates the merge.
+
+        On a mesh every device receives only its own vDPUs' rows: a
+        device array is padded and reshaped by one program whose output
+        is laid out on the mesh, and a host array is sliced on the host
+        per device.  The whole dataset is never staged on one device.
         """
         n = X.shape[0]
         per = -(-n // self.n_vdpus)              # ceil
         pad = per * self.n_vdpus - n
 
-        def place(a):
-            a = jnp.asarray(a)
+        def layout(a, xp):
             if pad:
-                a = jnp.concatenate(
-                    [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)], axis=0)
-            a = a.reshape((self.n_vdpus, per) + a.shape[1:])
-            if self.mesh is not None:
-                a = jax.device_put(a, self.data_sharding())
-            return a
+                a = xp.concatenate(
+                    [a, xp.zeros((pad,) + a.shape[1:], a.dtype)], axis=0)
+            return a.reshape((self.n_vdpus, per) + a.shape[1:])
+
+        def place(a):
+            if self.mesh is None:
+                return layout(jnp.asarray(a), jnp)
+            sharding = self.data_sharding()
+            mesh_devices = set(self.mesh.devices.flat)
+            if isinstance(a, jax.Array) and not (
+                    a.committed and set(a.devices()) - mesh_devices):
+                return jax.jit(partial(layout, xp=jnp),
+                               out_shardings=sharding)(a)
+            host = layout(np.asarray(a), np)
+            return jax.make_array_from_callback(
+                host.shape, sharding, lambda idx: host[idx])
 
         # place() appends `pad` zero rows — zeros are exactly the mask
         # value for padding, so the mask goes in unpadded
@@ -242,7 +256,7 @@ class PimGrid:
         return shard_map(
             shard_body, mesh=self.mesh,
             in_specs=(P(), data_specs), out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(model, data)
 
     # -- merge-plan delegation ------------------------------------------

@@ -166,6 +166,10 @@ def quantize_symmetric(x: jax.Array, bits: int = 8, axis=None,
 
     ``axis=None`` -> per-tensor scale; ``axis=k`` -> scale per slice along
     every axis except ``k``'s complement (i.e. reduce over ``axis``).
+    Op by op (a fused program may round differently, and the mesh
+    collectives must match this bit for bit); each float32 temporary the
+    size of ``x`` is dropped as soon as the next op has consumed it, so a
+    resident dataset holds at most two of them at once.
     """
     x = jnp.asarray(x, jnp.float32)
     qmax = 2 ** (bits - 1) - 1
@@ -174,16 +178,16 @@ def quantize_symmetric(x: jax.Array, bits: int = 8, axis=None,
     else:
         amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / qmax
-    scaled = x / scale
     if stochastic:
         if key is None:
             raise ValueError("stochastic rounding requires a PRNG key")
         noise = jax.random.uniform(key, x.shape, jnp.float32)
-        q = jnp.floor(scaled + noise)
+        q = jnp.floor(x / scale + noise)
     else:
-        q = jnp.round(scaled)
+        q = jnp.round(x / scale)
     dtype = _INT_DTYPES[bits] if bits in _INT_DTYPES else jnp.int32
-    return Quantized(jnp.clip(q, -qmax - 1, qmax).astype(dtype), scale)
+    q = jnp.clip(q, -qmax - 1, qmax)
+    return Quantized(q.astype(dtype), scale)
 
 
 def quantize_fixed_scale(x: jax.Array, scale: jax.Array,
@@ -269,32 +273,34 @@ def fxp_matmul(a: jax.Array, b: jax.Array,
     )
 
 
-def _split_limbs(x: jax.Array):
-    """int16 -> (hi, lo) int8-range limbs with x = 256*hi + lo, lo∈[0,256).
-
-    This is the TPU-native widening trick (DESIGN.md §2): the MXU multiplies
-    8-bit operands natively, so a 16-bit multiply is four 8-bit passes —
-    structurally the same as the DPU's software-widened multiply, but run on
-    the systolic array."""
-    xi = x.astype(jnp.int32)
-    hi = (xi >> 8).astype(jnp.int16)          # arithmetic shift = floor/256
-    lo = (xi & 0xFF).astype(jnp.int16)        # unsigned low byte
-    return hi, lo
-
-
 def int8_limbs(x: jax.Array):
-    """``[(weight, limb)]`` decomposition into int8-range limbs.
+    """``[(weight, limb)]`` decomposition into signed int8 limbs, most
+    significant first: ``x == sum(weight * limb)``.
 
-    int8/uint8 pass through as a single limb; wider ints split via
-    ``_split_limbs`` (x = 256*hi + lo).  Limbs are int16-typed — the low
-    limb is unsigned [0, 256) — but every value fits a narrow multiply.
-    Shared by ``hybrid_dot`` (jnp path) and ``kernels.dispatch``'s
-    Pallas path so the two stay bit-identical by construction.
+    This is the TPU-native widening trick (DESIGN.md §2): the MXU
+    multiplies int8 operands natively (Mosaic has no int16 matmul), so a
+    wide multiply is several 8-bit passes — structurally the DPU's
+    software-widened multiply, run on the systolic array.  int8 passes
+    through as one limb.  Wider ints split signed: each low limb is in
+    [-128, 128) and its carry folds into the rest, so every limb is a
+    true ``int8``.  Two signed limbs cover only [-32896, 32639], so an
+    int16 takes three (the top one is 0 or 1).  Shared by ``hybrid_dot``
+    (jnp path) and ``kernels.dispatch``'s Pallas path so the two stay
+    bit-identical by construction.
     """
-    if x.dtype in (jnp.int8, jnp.uint8):
-        return [(1.0, x.astype(jnp.int16))]
-    hi, lo = _split_limbs(x)
-    return [(256.0, hi), (1.0, lo)]
+    if x.dtype == jnp.int8:
+        return [(1.0, x)]
+    if x.dtype not in (jnp.uint8, jnp.int16):
+        raise TypeError(f"int8_limbs takes int8/uint8/int16, got {x.dtype}")
+    n_limbs = 3 if x.dtype == jnp.int16 else 2
+    rest = x.astype(jnp.int32)
+    limbs = []
+    for i in range(n_limbs - 1):
+        lo = ((rest + 128) & 0xFF) - 128
+        limbs.append((float(256 ** i), lo.astype(jnp.int8)))
+        rest = (rest - lo) >> 8
+    limbs.append((float(256 ** (n_limbs - 1)), rest.astype(jnp.int8)))
+    return limbs[::-1]
 
 
 def hybrid_dot(a: jax.Array, b: jax.Array, *, k_chunk: int = 4096

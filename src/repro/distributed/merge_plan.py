@@ -136,7 +136,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.distributed import collectives as coll
 from repro.distributed import compression as comp
@@ -592,7 +592,7 @@ def cadence_round(grid, local_fn: Callable, update_fn: Callable,
         states, metrics = shard_map(
             shard_body, mesh=grid.mesh,
             in_specs=(P(), data_specs), out_specs=P(),
-            check_rep=False)(state, data)
+            check_vma=False)(state, data)
 
     inv = 1.0 / scale
     return (jax.tree.map(lambda x: x * inv, states),
@@ -693,7 +693,7 @@ def pipeline_fns(grid, local_fn: Callable, update_fn: Callable, *,
             fresh = shard_map(
                 compute_local, mesh=grid.mesh,
                 in_specs=(P(), data_specs(data)),
-                out_specs=P(axes), check_rep=False)(state, data)
+                out_specs=P(axes), check_vma=False)(state, data)
             return fresh, None
 
         def merge_fn(pending, ef):
@@ -709,7 +709,7 @@ def pipeline_fns(grid, local_fn: Callable, update_fn: Callable, *,
                           espec),
                 out_specs=(jax.tree.map(lambda _: P(), pending),
                            espec),
-                check_rep=False)(pending, ef)
+                check_vma=False)(pending, ef)
 
         def commit_fn(state, merged, mom):
             proposed, metrics = update_fn(state, merged)
@@ -761,7 +761,7 @@ def pipeline_fns(grid, local_fn: Callable, update_fn: Callable, *,
             lanes, metrics = shard_map(
                 phase_local, mesh=grid.mesh,
                 in_specs=(P(), data_specs(data)),
-                out_specs=(P(axes), P()), check_rep=False)(state, data)
+                out_specs=(P(axes), P()), check_vma=False)(state, data)
         return (lanes, state), metrics
 
     # top-k sparsification on the state wire rides the *delta*: a
@@ -790,7 +790,7 @@ def pipeline_fns(grid, local_fn: Callable, update_fn: Callable, *,
                           espec),
                 out_specs=(jax.tree.map(lambda _: P(), lanes),
                            espec),
-                check_rep=False)(lanes, ef)
+                check_vma=False)(lanes, ef)
         if delta_wire:
             avg = jax.tree.map(lambda s, d: s + d, start, avg)
         return (avg, start), ef

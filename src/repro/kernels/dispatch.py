@@ -20,13 +20,13 @@ Dispatch table (mlalgo hot spot -> Pallas kernel):
   ``lut_apply``       ``lut_activation``        logreg LUT sigmoid
   ==================  ========================  =======================
 
-Backend selection is automatic: on TPU the kernels lower to Mosaic; on
-CPU/GPU (this container) they run with ``interpret=True`` — jnp emulation
-that stays jit/vmap-compatible, so the same mlalgo code path is exercised
-everywhere.  ``use_kernels(False)`` flips every entry to its pure-jnp
-reference; parity tests and the before/after benchmarks use it.  The flag
-is read at *trace* time, so flipping it only affects functions traced
-afterwards (each ``train_*`` call traces afresh).
+Backend selection is automatic and made at trace time: on the TPU the
+kernels lower to Mosaic; on CPU/GPU they run with ``interpret=True`` —
+jnp emulation that stays jit/vmap-compatible, so the same mlalgo code
+path is exercised everywhere.  ``use_kernels(False)`` flips every entry
+to its pure-jnp reference; parity tests and the before/after benchmarks
+use it.  The flag is read at *trace* time, so flipping it only affects
+functions traced afterwards (each ``train_*`` call traces afresh).
 
 All dispatch functions accept per-row weights where the underlying
 statistic must ignore PimGrid shard padding, and every kernel pads
@@ -36,10 +36,10 @@ constraints.
 Block shapes are no longer fixed constants: every kernel call asks
 ``tuning.autotune.block_shapes`` for its tile sizes, keyed on
 ``(kernel, dtype, shape-bucket, backend)``.  Measured entries from the
-on-disk autotune cache win; otherwise a per-backend heuristic applies
-(MXU-aligned VMEM-bounded tiles on TPU, fewest-grid-steps blocks under
-interpret mode, where the kernel body runs once per grid step in
-Python).  See ``repro/tuning/autotune.py`` (``kernels/autotune.py`` is
+autotune table (``tuning.autotune.cache_path``) win; otherwise a
+per-backend heuristic applies (MXU-aligned VMEM-bounded tiles on TPU,
+fewest-grid-steps blocks under interpret mode, where the kernel body
+runs once per grid step).  See ``repro/tuning/autotune.py`` (``kernels/autotune.py`` is
 a back-compat re-export).
 
 Interaction with the scan engine's compile cache: ``PimGrid.make_runner``
@@ -79,9 +79,10 @@ from repro.kernels import kmeans_assign as _km
 from repro.kernels import lut_activation as _lut
 from repro.kernels import ref as _ref
 from repro.kernels import split_hist as _sh
-from repro.kernels.ops import INTERPRET
+from repro.kernels.ops import interpret_mode
 
 _ENABLED = [True]
+_LANES = 128                # minor dimension of a TPU vector tile
 
 
 def kernels_enabled() -> bool:
@@ -106,39 +107,61 @@ def use_kernels(enabled: bool):
 # ---------------------------------------------------------------------------
 
 def hybrid_matmul(a: jax.Array, b: jax.Array, *,
-                  k_chunk: int = 4096) -> jax.Array:
+                  k_chunk: int = 4096,
+                  backend: str | None = None) -> jax.Array:
     """Drop-in for ``quantize.hybrid_dot`` at the mlalgos call sites:
     (M, K) int8/int16 x (K, N) int8/int16 -> (M, N) float32.
 
-    Each >8-bit operand splits into int8-range limbs and every limb pair
-    runs through the ``fxp_matmul`` Pallas kernel, accumulated in int32
-    over K-chunks of ``k_chunk`` (|limb product| < 2^16, so each chunk
-    partial stays below 2^28 < 2^31); chunk/limb partials combine in
-    float32 — the same overflow guarantee as ``hybrid_dot``, exact for
-    any K.
+    Each operand splits into signed int8 limbs (``quantize.int8_limbs``).
+    ``b``'s limbs sit side by side in one ``(K, limbs*N)`` operand, so
+    each limb of ``a`` is one ``fxp_matmul`` pass over the data.  Limb
+    products accumulate in int32 over K-chunks of ``k_chunk``
+    (|limb product| <= 2^14, so each chunk partial stays below
+    2^26 < 2^31); chunk/limb partials combine in float32 in
+    ``hybrid_dot``'s order — bit-identical to it, exact for any K.
+
+    A tall, narrow product (a matvec's ``(rows, 1)``) is computed
+    transposed, ``(b^T a^T)^T``, so the kernel writes its long side
+    along the 128 lanes of a TPU tile instead of padding a handful of
+    columns out to 128.
+
+    ``backend`` is the backend the kernel is built for (default: JAX's
+    default backend): Mosaic on ``"tpu"``, interpret mode elsewhere.
+    Naming ``"tpu"`` compiles the training path's kernel calls for a
+    described TPU with no chip attached.
     """
     if not kernels_enabled():
         return qz.hybrid_dot(a, b, k_chunk=k_chunk)
-    K = a.shape[-1]
+    M, K = a.shape
+    N = b.shape[-1]
     k_chunk = min(k_chunk, K)
     n_chunks = -(-K // k_chunk)
-    # limbs are int16-typed (the low limb is unsigned [0, 256)); the
-    # block-shape table is keyed on what the kernel actually sees
-    blocks = _at.block_shapes("fxp_matmul", jnp.int16,
-                              (a.shape[0], k_chunk, b.shape[-1]))
+    b_limbs = qz.int8_limbs(b)
+    lb_all = jnp.concatenate([lb for _, lb in b_limbs], axis=1)
+    transposed = lb_all.shape[1] < _LANES <= M
+    interpret = interpret_mode(backend)
+
+    def chunk_product(la_c, lb_c):
+        if transposed:          # (limbs*N, M): rows of the result on lanes
+            la_c, lb_c = lb_c.T, la_c.T
+        blocks = _at.block_shapes("fxp_matmul", jnp.int8,
+                                  la_c.shape + lb_c.shape[1:], backend)
+        return _fxp.fxp_matmul(la_c, lb_c, interpret=interpret, **blocks)
+
     out = None
     for wa, la in qz.int8_limbs(a):
-        for wb, lb in qz.int8_limbs(b):
-            acc = None
-            for c in range(n_chunks):
-                part = _fxp.fxp_matmul(
-                    la[:, c * k_chunk:(c + 1) * k_chunk],
-                    lb[c * k_chunk:(c + 1) * k_chunk],
-                    interpret=INTERPRET, **blocks).astype(jnp.float32)
-                acc = part if acc is None else acc + part
-            term = (wa * wb) * acc
+        acc = None
+        for c in range(n_chunks):
+            part = chunk_product(
+                la[:, c * k_chunk:(c + 1) * k_chunk],
+                lb_all[c * k_chunk:(c + 1) * k_chunk]).astype(jnp.float32)
+            acc = part if acc is None else acc + part
+        for j, (wb, _) in enumerate(b_limbs):
+            cols = (acc[j * N:(j + 1) * N] if transposed
+                    else acc[:, j * N:(j + 1) * N])
+            term = (wa * wb) * cols
             out = term if out is None else out + term
-    return out
+    return out.T if transposed else out
 
 
 # ---------------------------------------------------------------------------
@@ -164,8 +187,8 @@ def kmeans_partials(x: jax.Array, centroids: jax.Array, w: jax.Array):
         blocks = _at.block_shapes(
             "kmeans_assign", x.dtype,
             (x.shape[0], x.shape[1], centroids.shape[0]))
-        return _km.kmeans_assign(x, centroids, w, interpret=INTERPRET,
-                                 **blocks)
+        return _km.kmeans_assign(x, centroids, w,
+                                 interpret=interpret_mode(), **blocks)
     return _ref.kmeans_assign_ref(x, centroids, w)
 
 
@@ -202,7 +225,7 @@ def level_histogram(node_idx: jax.Array, xbin: jax.Array, y: jax.Array,
             (xbin.shape[0], xbin.shape[1], n_nodes * n_bins * n_classes))
         return _sh.split_hist(node_idx, xbin, y, w, n_nodes=n_nodes,
                               n_bins=n_bins, n_classes=n_classes,
-                              interpret=INTERPRET, **blocks)
+                              interpret=interpret_mode(), **blocks)
     return _ref.split_hist_ref(node_idx, xbin, y, n_nodes, n_bins,
                                n_classes, w)
 
@@ -215,5 +238,6 @@ def lut_apply(table: lut_mod.LutTable, x: jax.Array) -> jax.Array:
     """Nearest-entry LUT evaluation of ``x`` (any shape)."""
     if kernels_enabled():
         return _lut.lut_activation(x, table.table, x_min=table.x_min,
-                                   x_max=table.x_max, interpret=INTERPRET)
+                                   x_max=table.x_max,
+                                   interpret=interpret_mode())
     return lut_mod.lut_lookup(table, x)

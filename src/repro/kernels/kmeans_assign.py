@@ -35,7 +35,11 @@ def _km_kernel(x_ref, c_ref, w_ref, sums_ref, counts_ref, sse_ref,
     x = x_ref[...].astype(jnp.float32)               # (bn, D)
     c = c_ref[...].astype(jnp.float32)               # (K, D)
     w = w_ref[...].astype(jnp.float32)               # (bn, 1)
+    # full f32 matmuls: SSE = |c|² − 2·x·c + |x|² cancels, so a
+    # single-pass bf16 product would shift it by far more than f32 noise
+    hi = jax.lax.Precision.HIGHEST
     xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                             precision=hi,
                              preferred_element_type=jnp.float32)
     c2 = jnp.sum(c * c, axis=1)
     d = c2[None, :] - 2.0 * xc                       # (bn, K) (+||x||²)
@@ -44,12 +48,14 @@ def _km_kernel(x_ref, c_ref, w_ref, sums_ref, counts_ref, sse_ref,
     onehot = (jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], K), 1)
               == a[:, None]).astype(jnp.float32) * w
     acc_s[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
+        onehot, x, (((0,), (0,)), ((), ())), precision=hi,
         preferred_element_type=jnp.float32)          # (K, D)
     acc_c[...] += jnp.sum(onehot, axis=0, keepdims=True)
-    best = jnp.min(d, axis=1)
-    x2 = jnp.sum(x * x, axis=1)
-    acc_e[0, 0] += jnp.sum((best + x2) * w[:, 0])
+    # SSE stays a (1, 1) vector end to end: Mosaic cannot store a
+    # scalar into VMEM
+    best = jnp.min(d, axis=1, keepdims=True)         # (bn, 1)
+    x2 = jnp.sum(x * x, axis=1, keepdims=True)
+    acc_e[...] += jnp.sum((best + x2) * w, axis=0, keepdims=True)
 
     @pl.when(i == n - 1)
     def _done():

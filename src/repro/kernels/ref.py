@@ -43,13 +43,14 @@ def fxp_matmul_ref(a, b):
 def kmeans_assign_ref(x, centroids, w=None):
     """x: (N,D) f32, centroids: (K,D), w: optional (N,) row weights ->
     (sums (K,D), counts (K,), sse ())."""
+    hi = jax.lax.Precision.HIGHEST                      # as the kernel
     d = (jnp.sum(centroids ** 2, axis=1)[None, :]
-         - 2.0 * x @ centroids.T)                       # (N,K) + ||x||²
+         - 2.0 * jnp.dot(x, centroids.T, precision=hi))  # (N,K) + ||x||²
     a = jnp.argmin(d, axis=1)
     onehot = jax.nn.one_hot(a, centroids.shape[0], dtype=x.dtype)
     if w is not None:
         onehot = onehot * w[:, None]
-    sums = onehot.T @ x
+    sums = jnp.dot(onehot.T, x, precision=hi)
     counts = jnp.sum(onehot, axis=0)
     best = jnp.take_along_axis(d, a[:, None], axis=1)[:, 0]
     contrib = best + jnp.sum(x * x, axis=1)

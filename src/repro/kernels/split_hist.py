@@ -2,10 +2,12 @@
 
 Builds H[node, feature, bin, class] counts for one level of CART growth.
 The DPU version scatters scalar increments; the TPU version turns the
-scatter into a one-hot matmul: for a block of rows, a (rows, nodes*bins*
-classes) one-hot of the combined index is contracted against a (rows, F)
-weight column on the MXU, accumulating (F, nodes*bins*classes) partials
-in VMEM scratch across the sequential row-block grid.
+scatter into a one-hot matmul: for a block of rows and one feature at a
+time, a (nodes*bins*classes, rows) one-hot of the combined index is
+contracted against the row-weight vector on the MXU, accumulating
+(F, nodes*bins*classes) partials in VMEM scratch across the sequential
+row-block grid.  Rows run along lanes, so every input block and every
+histogram row is lane-dense.
 
 Rows carry a weight ``w`` (the PimGrid 0/1 row mask), so shard padding —
 and the zero-padding used to round N up to a block multiple — adds
@@ -31,24 +33,29 @@ def _hist_kernel(node_ref, xbin_ref, y_ref, w_ref, h_ref, acc, *,
     def _init():
         acc[...] = jnp.zeros_like(acc)
 
-    node = node_ref[...]                          # (bn, 1) int32
-    xbin = xbin_ref[...]                          # (bn, F) int32
-    y = y_ref[...]                                # (bn, 1) int32
-    w = w_ref[...].astype(jnp.float32)            # (bn, 1)
-    bn, F = xbin.shape
+    # rows run along lanes: every input block is a lane-dense (., bn) row
+    node = node_ref[...]                          # (1, bn) int32
+    y = y_ref[...]                                # (1, bn) int32
+    w = w_ref[...].astype(jnp.float32)            # (1, bn)
+    F, bn = xbin_ref.shape
     nbc = n_nodes * n_bins * n_classes
-    # combined (node, bin, class) index per (row, feature)
-    comb = ((node * n_bins + xbin) * n_classes + y)       # (bn, F)
-    ent = jax.lax.broadcasted_iota(jnp.int32, (bn, F, nbc), 2)
-    onehot = (ent == comb[..., None]).astype(jnp.float32)  # (bn,F,nbc)
-    # contract rows on the MXU: (F, bn) x (bn, nbc) per feature, each row
-    # weighted by its mask
-    wcol = jnp.broadcast_to(w[None, :, :], (F, bn, 1))
-    part = jax.lax.dot_general(
-        onehot.transpose(1, 0, 2), wcol,
-        (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)               # (F, nbc, 1)
-    acc[...] += part[:, :, 0]
+    ent = jax.lax.broadcasted_iota(jnp.int32, (nbc, bn), 0)
+
+    # one feature at a time keeps a single (nbc, bn) one-hot live
+    def feature(f, carry):
+        xbin = xbin_ref[pl.ds(f, 1), :]           # (1, bn) int32
+        # combined (node, bin, class) index per row
+        comb = (node * n_bins + xbin) * n_classes + y
+        onehot = (ent == comb).astype(jnp.float32)          # (nbc, bn)
+        # contract rows on the MXU, each row weighted by its mask:
+        # (1, bn) x (nbc, bn)^T -> a lane-dense (1, nbc) histogram row
+        part = jax.lax.dot_general(
+            w, onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc[pl.ds(f, 1), :] += part
+        return carry
+
+    jax.lax.fori_loop(0, F, feature, 0)
 
     @pl.when(i == n - 1)
     def _done():
@@ -61,7 +68,8 @@ def split_hist(node_idx: jax.Array, xbin: jax.Array, y: jax.Array,
                block_n: int = 512, interpret: bool = False) -> jax.Array:
     """node_idx (N,), xbin (N,F), y (N,), w optional (N,) row weights ->
     H (n_nodes, F, n_bins, n_classes) f32.  N is zero-padded (with w=0)
-    to a block multiple, so any N works."""
+    to a block multiple, so any N works.  On the TPU ``block_n`` must
+    be a multiple of 128 or cover all of N (rows run along lanes)."""
     N, F = xbin.shape
     bn = min(block_n, N)
     nbc = n_nodes * n_bins * n_classes
@@ -77,19 +85,16 @@ def split_hist(node_idx: jax.Array, xbin: jax.Array, y: jax.Array,
 
     kernel = functools.partial(_hist_kernel, n_nodes=n_nodes,
                                n_bins=n_bins, n_classes=n_classes)
+    row = pl.BlockSpec((1, bn), lambda i: (0, i))
     h = pl.pallas_call(
         kernel,
         grid=(Np // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bn, F), lambda i: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-        ],
+        in_specs=[row, pl.BlockSpec((F, bn), lambda i: (0, i)), row, row],
         out_specs=pl.BlockSpec((F, nbc), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((F, nbc), jnp.float32),
         scratch_shapes=[pltpu.VMEM((F, nbc), jnp.float32)],
         interpret=interpret,
-    )(node_idx[:, None], xbin, y[:, None], w[:, None])
+    )(node_idx.astype(jnp.int32)[None, :], xbin.astype(jnp.int32).T,
+      y.astype(jnp.int32)[None, :], w[None, :])
     # (F, nodes*bins*classes) -> (nodes, F, bins, classes)
     return h.reshape(F, n_nodes, n_bins, n_classes).transpose(1, 0, 2, 3)

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell on
 the production meshes and record memory/cost/collective evidence.
 
-The two lines above MUST stay the first statements: jax locks the device
-count at first init, and only the dry-run wants 512 placeholder devices.
+Run as a script, it sets ``XLA_FLAGS`` for 512 placeholder host
+devices before JAX first initializes its backend; importing the module
+changes nothing.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun                 # full matrix
@@ -18,6 +16,7 @@ import argparse
 import dataclasses
 import gzip
 import json
+import os
 import time
 import traceback
 
@@ -227,4 +226,6 @@ def main():
 
 
 if __name__ == "__main__":
+    # before the first backend init: jax locks the device count then
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     raise SystemExit(main())

@@ -1,12 +1,3 @@
-import os
-
-# must be set before the first jax init; override to smoke-test the
-# lowering on fewer fake devices (the default meshes need 256/512)
-os.environ.setdefault(
-    "XLA_FLAGS",
-    "--xla_force_host_platform_device_count="
-    + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
-
 """Dry-run of the PAPER'S OWN workloads at pod scale: a Workload plugin
 (logistic regression by default, ``--workload svm`` / ``multinomial``
 for the PIM-Opt companions) on a PimGrid of 4,096 virtual DPUs spread
@@ -66,6 +57,7 @@ output JSON (``merge_fallback_warnings``).
 import argparse
 import dataclasses
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -343,4 +335,11 @@ def main():
 
 
 if __name__ == "__main__":
+    # must be set before the first jax backend init; override to
+    # smoke-test the lowering on fewer fake devices (the default meshes
+    # need 256/512)
+    os.environ.setdefault(
+        "XLA_FLAGS",
+        "--xla_force_host_platform_device_count="
+        + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
     main()

@@ -14,18 +14,27 @@ Topology (TPU v5e target):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the engine places its
+    own data and asks only for ``shard_map`` and sharding constraints,
+    which ``Explicit`` axes (the default since JAX 0.9) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (fake or real) local devices exist —
     used by tests that exercise sharded code paths on CPU."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def make_pim_mesh(pods: int = 1, data: int | None = None):
@@ -44,4 +53,4 @@ def make_pim_mesh(pods: int = 1, data: int | None = None):
             f"pods={pods} does not divide the {n} available devices")
     if data is None:
         data = n // pods
-    return jax.make_mesh((pods, data), ("pod", "data"))
+    return _auto_mesh((pods, data), ("pod", "data"))

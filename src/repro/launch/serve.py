@@ -29,6 +29,7 @@ from repro.core.mlalgos.linreg import LinReg
 from repro.core.mlalgos.multinomial import MultinomialLogReg
 from repro.core.mlalgos.svm import LinearSVM
 from repro.serving import MicroBatchQueue, ModelRegistry
+from repro.compile_cache import place_compile_cache
 
 
 def build_workload(name: str, precision: str):
@@ -128,4 +129,5 @@ def main():
 
 
 if __name__ == "__main__":
+    place_compile_cache()
     main()

@@ -23,7 +23,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common as cm
@@ -136,5 +136,5 @@ def moe_ffn(cfg: cm.ModelConfig, p: dict, x: jax.Array
         return y.reshape(Bl, Sl, d), aux
 
     y, aux = shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
-                       out_specs=(x_spec, P()), check_rep=False)(p, x)
+                       out_specs=(x_spec, P()), check_vma=False)(p, x)
     return y, aux

@@ -243,7 +243,7 @@ def _sharded_lookup(table: jax.Array, tokens: jax.Array) -> jax.Array:
     one psum over `model` combines; the transpose is a local scatter
     into the rank's (V/16, d) slice."""
     from repro.distributed.sharding import current_rules
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     rules = current_rules()
@@ -270,7 +270,7 @@ def _sharded_lookup(table: jax.Array, tokens: jax.Array) -> jax.Array:
     return shard_map(
         body, mesh=rules.mesh,
         in_specs=(P(vocab_ax, None), P(dp, None)),
-        out_specs=P(dp, None, None), check_rep=False)(table, tokens)
+        out_specs=P(dp, None, None), check_vma=False)(table, tokens)
 
 
 def _head(cfg, params, x):

@@ -49,7 +49,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.distributed import collectives as coll
 from repro.distributed import compression as comp
@@ -219,7 +219,7 @@ def survivor_runners(grid, local_fn, update_fn, *, merge_every: int,
                 shard_body, mesh=grid.mesh,
                 in_specs=(P(), P(axes), espec_of(ef), data_specs),
                 out_specs=(P(), espec_of(ef), P()),
-                check_rep=False)(state, mask, ef, data)
+                check_vma=False)(state, mask, ef, data)
             return (new_state, mask, ef), metrics
 
     del inv_metrics  # masked mean replaces the uniform 1/n scaling

@@ -15,10 +15,13 @@ module instead:
 
 All numbers are per-device (post-partitioning shapes).  Terms:
 
-  compute    = dot_flops / PEAK_FLOPS
-  memory     = traffic_bytes / HBM_BW
-  collective = Σ op_bytes * ring_factor(group) / ICI_BW   (DCN-aware:
-               groups that span pods use DCN_BW_PER_CHIP)
+  compute    = dot_flops / peaks.flops_bf16
+  memory     = traffic_bytes / peaks.hbm_bw
+  collective = Σ op_bytes * ring_factor(group) / peaks.ici_bw_per_link
+               (DCN-aware: groups that span pods use
+               peaks.dcn_bw_per_chip)
+
+``peaks`` is a ``roofline.hw.ChipPeaks`` (default: the v5e target).
 """
 
 from __future__ import annotations
@@ -486,14 +489,16 @@ def _group_spans_pods(op: Op, n_devices: int, pod_size: int = 256) -> bool:
 
 
 def roofline_terms(parsed: ParsedHLO, cost: dict, *, n_chips: int,
-                   per_device_program: bool = True) -> dict:
-    """Three-term roofline (seconds, per step) + bottleneck."""
+                   per_device_program: bool = True,
+                   peaks: hw.ChipPeaks = hw.TPU_V5E) -> dict:
+    """Three-term roofline (seconds, per step) + bottleneck, priced at
+    ``peaks`` (default: the v5e target)."""
     flops = parsed.dot_flops + parsed.conv_flops
     raw_flops = float(cost.get("flops", 0.0) or 0.0)
     raw_bytes = float(cost.get("bytes accessed", 0.0) or 0.0)
 
-    compute_s = flops / hw.PEAK_FLOPS_BF16
-    memory_s = parsed.traffic_bytes / hw.HBM_BW
+    compute_s = flops / peaks.flops_bf16
+    memory_s = parsed.traffic_bytes / peaks.hbm_bw
 
     ici_s = 0.0
     dcn_s = 0.0
@@ -507,9 +512,9 @@ def roofline_terms(parsed: ParsedHLO, cost: dict, *, n_chips: int,
             factor = 1.0
         t = c.bytes * c.multiplier * factor
         if c.crosses_pod:
-            dcn_s += t / hw.DCN_BW_PER_CHIP
+            dcn_s += t / peaks.dcn_bw_per_chip
         else:
-            ici_s += t / hw.ICI_BW_PER_LINK
+            ici_s += t / peaks.ici_bw_per_link
     collective_s = ici_s + dcn_s
 
     terms = {"compute_s": compute_s, "memory_s": memory_s,
@@ -534,7 +539,8 @@ def predict_round(parsed: ParsedHLO, *, n_chips: int = 1,
                   cadence: int = 1, wire_bytes: float = 0.0,
                   overlap: bool = False, baseline_cadence: int = 1,
                   encode_bytes: float = 0.0,
-                  wire_bw: float = None) -> dict:
+                  wire_bw: float = None,
+                  peaks: hw.ChipPeaks = hw.TPU_V5E) -> dict:
     """Per-round time prediction for a candidate merge plan — the
     consumable entry point the tuning layer builds its cost model on
     (``repro.tuning.CostModel``).
@@ -556,9 +562,9 @@ def predict_round(parsed: ParsedHLO, *, n_chips: int = 1,
       encode/decode traffic a compressed wire costs (a few passes over
       the dense tree), so compression only wins when the wire saving
       beats its encode cost.  ``wire_bw`` overrides the slow hop's
-      bandwidth (default ``hw.DCN_BW_PER_CHIP``): a single-chip grid
+      bandwidth (default ``peaks.dcn_bw_per_chip``): a single-chip grid
       has no inter-chip link at all — its "slow hop" is an in-memory
-      reduction moving at ``hw.HBM_BW`` — and pricing it at DCN speed
+      reduction moving at ``peaks.hbm_bw`` — and pricing it at DCN speed
       would make compression look like a win on a hop that is pure
       compute (``repro.tuning.CostModel`` passes the right one).
     * a candidate round then costs ``cadence * t_local + t_merge``, or
@@ -568,11 +574,11 @@ def predict_round(parsed: ParsedHLO, *, n_chips: int = 1,
     Returns a dict with those terms plus ``round_s`` and
     ``us_per_step`` (the ranking key).
     """
-    terms = roofline_terms(parsed, {}, n_chips=n_chips)
+    terms = roofline_terms(parsed, {}, n_chips=n_chips, peaks=peaks)
     base = max(int(baseline_cadence), 1)
     t_local = max(terms["compute_s"], terms["memory_s"]) / base
-    t_encode = float(encode_bytes) / hw.HBM_BW
-    bw = hw.DCN_BW_PER_CHIP if wire_bw is None else float(wire_bw)
+    t_encode = float(encode_bytes) / peaks.hbm_bw
+    bw = peaks.dcn_bw_per_chip if wire_bw is None else float(wire_bw)
     t_merge = terms["ici_s"] + t_encode + \
         max(terms["dcn_s"], float(wire_bytes) / bw)
     k = max(int(cadence), 1)

@@ -2,8 +2,7 @@
 
 ``Workload.predict`` (core/mlalgos) is the forward pass;
 :class:`PredictRunner` compiles it once per (workload, bucket,
-precision) behind a pad-to-bucket ladder with donated, double-buffered
-staging; :class:`ModelRegistry` versions checkpointed states behind an
+precision) behind a pad-to-bucket ladder with double-buffered staging; :class:`ModelRegistry` versions checkpointed states behind an
 atomic hot-swap; :class:`MicroBatchQueue` coalesces single-row requests
 into bucket-sized micro-batches under a max-wait deadline with
 backpressure and per-request latency accounting.  See

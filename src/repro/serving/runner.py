@@ -21,9 +21,8 @@ engine's signature-keyed compile cache exists to prevent.  The
   by value, so two runners serving equal estimator configurations share
   executables, including across registry hot-swaps: the model state is
   an *argument* of the compiled function, never a baked-in constant);
-* the padded input buffer is donated on backends where donation is real
-  (``merge_plan.donating_backend``) — request buffers are single-use by
-  construction, so the executable may reuse their memory;
+* the padded input buffer is not donated: a prediction never has the
+  request's ``(bucket, features)`` shape, so XLA could not reuse it;
 * :meth:`run_stream` double-buffers host→device staging behind compute,
   the same idiom ``overlap_merge`` / the streaming ``Prefetcher`` use:
   dispatch for batch *i* returns before its result materializes, so
@@ -94,7 +93,6 @@ class PredictRunner:
             return _w.predict(state, X)
 
         self._fwd = fwd
-        self._donate = mp.donating_backend()
 
         self.bucket_hits = 0
         self.compile_misses = 0
@@ -111,7 +109,7 @@ class PredictRunner:
         """The executable for one (bucket, features) cell — compiled at
         most once per (workload, bucket, d, state shapes, backend)."""
         key = ("serving", mp.fn_signature(self._fwd), bucket, d,
-               self._state_aval(), self._donate)
+               self._state_aval())
         with self._lock:
             if self.grid is not None:
                 hit = mp.cache_get(self.grid, key)
@@ -122,9 +120,7 @@ class PredictRunner:
             self.compile_misses += 1
             if self._warm:
                 self.steady_compile_misses += 1
-            donate = (1,) if self._donate else ()
-            jf = jax.jit(self._fwd, donate_argnums=donate)
-            exe = jf.lower(
+            exe = jax.jit(self._fwd).lower(
                 self.state,
                 jax.ShapeDtypeStruct((bucket, d), jnp.float32)).compile()
             if self.grid is not None:

@@ -20,8 +20,9 @@ they fit in memory).  This module owns that decision:
 * ``autotune(kernel, shape, dtype)`` — the measured path: times each
   candidate block shape on representative inputs with the real kernel
   and persists the winner to the on-disk cache
-  (``$REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/autotune_blocks.json``),
-  so the cost is paid once per machine, not per process.
+  (``$REPRO_AUTOTUNE_CACHE`` or ``.autotune/blocks.json`` in the
+  checkout), so the cost is paid once per checkout, not per process.
+  It raises when no candidate lowers.
   ``measure_candidates`` exposes the raw timings as the same
   ``Measurement`` records the plan controller consumes.
 
@@ -56,12 +57,18 @@ from repro.tuning.measurement import Measurement
 # interpret-mode blocks are capped by element budgets rather than VMEM:
 # the whole block materializes as a jnp array per grid step.
 _INTERPRET_ELEMS = 1 << 22       # ~16 MB of f32 per operand block
-_ONEHOT_ELEMS = 1 << 24          # split_hist materializes (bn, F, n*b*c)
+_ONEHOT_ELEMS = 1 << 24          # split_hist's F (n*b*c, bn) one-hots
 _VMEM_ELEMS = 1 << 20            # ~4 MB of f32 — conservative VMEM share
+# split_hist's budget: half of v5e's 16 MiB default scoped VMEM in f32
+# elements, the rest left to Mosaic's own copies and the input buffers
+_SPLIT_HIST_VMEM_ELEMS = 1 << 21
 
 _CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-_DEFAULT_CACHE = os.path.join("~", ".cache", "repro",
-                              "autotune_blocks.json")
+# inside the checkout (and ignored by git), so a run reads no table it did
+# not write: on a fresh checkout the heuristic applies
+_DEFAULT_CACHE = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".autotune", "blocks.json")
 
 _lock = threading.Lock()
 _cache: Optional[dict] = None
@@ -82,7 +89,8 @@ _DIM_NAMES: Dict[str, Dict[str, int]] = {
 
 
 def cache_path() -> str:
-    return os.path.expanduser(os.environ.get(_CACHE_ENV, _DEFAULT_CACHE))
+    return os.path.normpath(os.path.expanduser(
+        os.environ.get(_CACHE_ENV, _DEFAULT_CACHE)))
 
 
 def _load_cache() -> dict:
@@ -196,14 +204,17 @@ def _heuristic(kernel: str, dtype, shape: Sequence[int],
 
     if kernel == "split_hist":
         N, F, nbc = shape
-        # the kernel materializes a (bn, F, nbc) one-hot per grid step
-        # (interpret) / VMEM tile (TPU) — bound bn by the one-hot budget
-        budget = _ONEHOT_ELEMS if not on_tpu else _VMEM_ELEMS
-        bn = max(1, budget // max(F * nbc, 1))
-        bn = min(N, bn, 1024 if not on_tpu else 512)
         if on_tpu:
-            bn = max(8, (bn // 8) * 8)
-        return {"block_n": bn}
+            # VMEM holds the (F, nbc) accumulator and its double-buffered
+            # output block, and per row block one feature's (nbc, bn)
+            # iota, compare mask and f32 one-hot.  Rows run along lanes,
+            # so bn is a multiple of 128 unless it covers all of N.
+            bn = (_SPLIT_HIST_VMEM_ELEMS - 3 * F * nbc) // (3 * nbc)
+            bn = min(1024, max(128, bn // 128 * 128))
+            return {"block_n": N if N <= bn else bn}
+        # interpret mode: bound the F one-hots of a grid step together
+        bn = max(1, _ONEHOT_ELEMS // max(F * nbc, 1))
+        return {"block_n": min(N, bn, 1024)}
 
     raise ValueError(f"unknown kernel {kernel!r}")
 
@@ -335,9 +346,9 @@ def _bench_harness(kernel: str, shape: Sequence[int], dtype,
     from repro.kernels import fxp_matmul as _fxp
     from repro.kernels import kmeans_assign as _km
     from repro.kernels import split_hist as _sh
-    from repro.kernels.ops import INTERPRET
+    from repro.kernels.ops import interpret_mode
 
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     rng = np.random.default_rng(0)
 
     if kernel == "fxp_matmul":
@@ -385,19 +396,26 @@ def measure_candidates(kernel: str, shape: Sequence[int], dtype=None,
     backend and return the raw timings as :class:`Measurement` records
     — the same rows the plan controller's trace speaks, so kernel-level
     and plan-level tuning decisions are directly comparable.  Candidates
-    that fail to lower are skipped."""
+    that fail to lower are skipped; when none lowers, the last failure
+    is raised."""
     backend = jax.default_backend()
     dtype, run = _bench_harness(kernel, shape, dtype, interpret)
     tkey = table_key(kernel, dtype, shape, backend)
     out: List[Measurement] = []
+    failure: Optional[Exception] = None
     for blocks in _candidates(kernel, dtype, shape, backend):
         try:
             us = _time_call(lambda b=blocks: run(b))
-        except Exception:           # a candidate may not lower — skip it
+        except Exception as e:      # a candidate may not lower — skip it
+            failure = e
             continue
         out.append(Measurement(
             key=(kernel, tkey, tuple(sorted(blocks.items()))),
             seconds=us * 1e-6, steps=1, source="autotune"))
+    if not out:
+        raise RuntimeError(
+            f"no {kernel} block shape lowers for shape {tuple(shape)} on "
+            f"{backend}") from failure
     return out
 
 
@@ -414,13 +432,8 @@ def autotune(kernel: str, shape: Sequence[int], dtype=None,
     dtype_r, _ = _bench_harness(kernel, shape, dtype, interpret)
     measured = measure_candidates(kernel, shape, dtype,
                                   interpret=interpret)
-    if measured:
-        best = min(measured, key=lambda m: m.seconds)
-        best_blocks = dict(best.key[2])
-        best_us = best.seconds * 1e6
-    else:
-        best_blocks = _heuristic(kernel, dtype_r, shape, backend)
-        best_us = -1.0
+    best = min(measured, key=lambda m: m.seconds)
+    best_blocks = dict(best.key[2])
     _store(table_key(kernel, dtype_r, shape, backend), best_blocks,
-           best_us)
-    return dict(best_blocks)
+           best.seconds * 1e6)
+    return best_blocks

@@ -33,6 +33,7 @@ import jax
 from repro.distributed import compression as comp
 from repro.distributed.compression import CompressionConfig
 from repro.roofline import analysis as ra
+from repro.roofline import hw
 from repro.tuning.measurement import Measurement
 
 
@@ -72,6 +73,7 @@ class CostModel:
     wire: Any
     n_chips: int = 1
     baseline_cadence: int = 1
+    peaks: hw.ChipPeaks = hw.TPU_V5E
 
     # encode/decode passes a compressed wire costs over the dense tree
     # (quantize + dequantize + error-feedback update)
@@ -99,7 +101,10 @@ class CostModel:
         wire = mp.wire_spec(grid, local_fn, update_fn, state, data,
                             merge_every=2)
         n_chips = 1 if grid.mesh is None else grid.mesh.size
-        model = cls(parsed=parsed, wire=wire, n_chips=int(n_chips))
+        device = (jax.devices()[0] if grid.mesh is None
+                  else grid.mesh.devices.flat[0])
+        model = cls(parsed=parsed, wire=wire, n_chips=int(n_chips),
+                    peaks=hw.peaks_for(device))
         mp.cache_put(grid, key, model, local_fn, update_fn)
         return model
 
@@ -132,7 +137,8 @@ class CostModel:
             wire_bytes=self.wire_bytes(compression), overlap=hides,
             baseline_cadence=self.baseline_cadence,
             encode_bytes=encode,
-            wire_bw=ra.hw.HBM_BW if self.n_chips == 1 else None)
+            wire_bw=self.peaks.hbm_bw if self.n_chips == 1 else None,
+            peaks=self.peaks)
         row["compression"] = compression_tag(compression)
         row["overlap"] = bool(overlap)
         return row

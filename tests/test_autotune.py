@@ -16,7 +16,6 @@ from repro.kernels import autotune as at
 from repro.kernels import dispatch
 from repro.kernels import fxp_matmul as _fxp
 from repro.kernels import kmeans_assign as _km
-from repro.kernels.ops import INTERPRET
 
 
 @pytest.fixture
@@ -26,6 +25,19 @@ def tmp_cache(tmp_path, monkeypatch):
     at.reset_cache_for_tests()
     yield path
     at.reset_cache_for_tests()
+
+
+class TestNothingLowers:
+    def test_autotune_raises_instead_of_storing_a_guess(self, tmp_cache,
+                                                        monkeypatch):
+        def refuse(*a, **k):
+            raise RuntimeError("Mosaic refused the block")
+
+        from repro.tuning import autotune as tuning_autotune
+        monkeypatch.setattr(tuning_autotune, "_time_call", refuse)
+        with pytest.raises(RuntimeError, match="no fxp_matmul block"):
+            at.autotune("fxp_matmul", (64, 128, 32))
+        assert not os.path.exists(tmp_cache)
 
 
 class TestBuckets:
@@ -97,7 +109,7 @@ class TestMeasuredCache:
         assert entry["blocks"] == best
         # a fresh in-memory cache reads the measured entry back
         at.reset_cache_for_tests()
-        assert at.block_shapes("fxp_matmul", jnp.int16,
+        assert at.block_shapes("fxp_matmul", jnp.int8,
                                (64, 128, 32)) == best
 
     def test_measured_entry_clamped_to_smaller_call(self, tmp_cache):

@@ -53,6 +53,32 @@ class TestHybridMatmul:
         assert out.dtype == jnp.float32
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
+    @pytest.mark.parametrize("M,N", [(37, 5), (300, 1)],
+                             ids=["wide", "tall-matvec"])
+    @pytest.mark.parametrize("adt,bdt", [(jnp.int8, jnp.int8),
+                                         (jnp.int8, jnp.int16),
+                                         (jnp.int16, jnp.int16)])
+    def test_extreme_values_bit_identical(self, adt, bdt, M, N):
+        """Full-range operands, both ends included (int16's top values
+        take the third signed limb), in both the plain and the
+        transposed (tall, narrow) orientation."""
+        K = 19
+        rng = np.random.default_rng(7)
+
+        def draw(shape, dt):
+            info = np.iinfo(dt)
+            v = rng.integers(info.min, info.max + 1, shape).astype(dt)
+            v.flat[:2] = (info.min, info.max)
+            return jnp.asarray(v)
+
+        a, b = draw((M, K), adt), draw((K, N), bdt)
+        out = dispatch.hybrid_matmul(a, b)
+        want = qz.hybrid_dot(a, b)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        exact = np.asarray(a, np.int64) @ np.asarray(b, np.int64)
+        np.testing.assert_allclose(np.asarray(out, np.float64), exact,
+                                   rtol=1e-6)
+
     def test_k_chunking_matches_hybrid_dot(self):
         """K larger than k_chunk must chunk (overflow guard parity)."""
         a = jax.random.randint(KEY, (8, 100), -32768, 32767

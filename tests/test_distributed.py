@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_host_mesh
@@ -24,7 +24,7 @@ MESH = make_host_mesh(1, 1)
 
 def _in_mesh(fn, *args):
     return shard_map(fn, mesh=MESH, in_specs=P(), out_specs=P(),
-                     check_rep=False)(*args)
+                     check_vma=False)(*args)
 
 
 class TestCollectives:
@@ -42,7 +42,7 @@ class TestCollectives:
             return coll.quantized_psum_ef(v, e, "data", bits=8)
 
         out, err = shard_map(body, mesh=MESH, in_specs=(P(), P()),
-                             out_specs=(P(), P()), check_rep=False)(x, e0)
+                             out_specs=(P(), P()), check_vma=False)(x, e0)
         np.testing.assert_allclose(np.asarray(out + err), np.asarray(x),
                                    atol=1e-6)
 
@@ -65,7 +65,7 @@ class TestCompression:
         specs = jax.tree.map(lambda _: P(), grads)
         out, _ = shard_map(body, mesh=MESH, in_specs=(specs, specs),
                            out_specs=(specs, specs),
-                           check_rep=False)(grads, err)
+                           check_vma=False)(grads, err)
         np.testing.assert_array_equal(np.asarray(out["a"]), np.ones(8))
 
     def test_topk_sparsify(self):

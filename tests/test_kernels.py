@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.kmeans_assign import kmeans_assign
+from repro.kernels.split_hist import split_hist
 from repro.core import lut as lutm
 
 KEY = jax.random.PRNGKey(0)
@@ -113,6 +115,22 @@ class TestKMeansAssign:
         np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
         np.testing.assert_allclose(float(e1), float(e2), rtol=1e-5)
 
+    @pytest.mark.parametrize("block_n", [4096, 1024, 384])
+    def test_vector_sse_accumulator_matches_ref(self, block_n):
+        """The (1, 1) SSE accumulator sums across every grid step — one
+        block, several, and a ragged zero-weight-padded tail."""
+        x = jax.random.normal(KEY, (4096, 16), jnp.float32)
+        c = jax.random.normal(jax.random.fold_in(KEY, 22), (8, 16))
+        w = (jax.random.uniform(jax.random.fold_in(KEY, 23), (4096,))
+             > 0.2).astype(jnp.float32)
+        s1, c1, e1 = kmeans_assign(x, c, w, block_n=block_n,
+                                   interpret=True)
+        s2, c2, e2 = ref.kmeans_assign_ref(x, c, w)
+        np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
+                                   atol=1e-3, rtol=1e-4)
+        np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2))
+        np.testing.assert_allclose(float(e1), float(e2), rtol=1e-5)
+
 
 class TestSplitHist:
     @pytest.mark.parametrize("N,F,nodes,bins,classes", [
@@ -148,4 +166,23 @@ class TestSplitHist:
         h1 = ops.split_hist(node, xb, y, w, n_nodes=4, n_bins=8,
                             n_classes=2)
         h2 = ref.split_hist_ref(node, xb, y, 4, 8, 2, w)
+        np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
+
+    @pytest.mark.parametrize("block_n", [2048, 128])
+    def test_depth6_level_matches_ref(self, block_n):
+        """The deepest level of a depth-6 tree (32 nodes x 32 bins x 4
+        classes, 16 features) over one vDPU's 2,048 rows, in one block
+        and in the TPU heuristic's 128-row blocks."""
+        N, F, nodes, bins, classes = 2048, 16, 32, 32, 4
+        node = jax.random.randint(KEY, (N,), 0, nodes)
+        xb = jax.random.randint(jax.random.fold_in(KEY, 17), (N, F), 0,
+                                bins)
+        y = jax.random.randint(jax.random.fold_in(KEY, 18), (N,), 0,
+                               classes)
+        w = (jax.random.uniform(jax.random.fold_in(KEY, 19), (N,))
+             > 0.1).astype(jnp.float32)
+        h1 = split_hist(node, xb, y, w, n_nodes=nodes, n_bins=bins,
+                        n_classes=classes, block_n=block_n,
+                        interpret=True)
+        h2 = ref.split_hist_ref(node, xb, y, nodes, bins, classes, w)
         np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))

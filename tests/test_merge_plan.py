@@ -536,7 +536,7 @@ class TestTopK:
         """The mesh-path collective: each participant sparsifies its
         error-fed slice; kept mass sums, dropped mass lands in the
         residual."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_host_mesh
         from repro.distributed import collectives as coll
@@ -550,7 +550,7 @@ class TestTopK:
 
         out, new_e = shard_map(
             body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
-            check_rep=False)(x, e)
+            check_vma=False)(x, e)
         assert int((np.asarray(out) != 0).sum()) == 16
         np.testing.assert_allclose(np.asarray(out + new_e),
                                    np.asarray(x), atol=1e-6)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import datasets, make_cpu_grid
@@ -289,7 +289,7 @@ class TestIntegerLeafPassthrough:
         specs = jax.tree.map(lambda _: P(), grads)
         out, new_err = shard_map(
             body, mesh=mesh, in_specs=(specs, specs),
-            out_specs=(specs, specs), check_rep=False)(grads, err)
+            out_specs=(specs, specs), check_vma=False)(grads, err)
         # integer leaf: bit-exact, no error accumulated
         np.testing.assert_array_equal(np.asarray(out["hist"]),
                                       np.arange(300, dtype=np.int32))

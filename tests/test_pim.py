@@ -71,6 +71,25 @@ def test_mesh_path_matches_single_device():
                                rtol=1e-5)
 
 
+@pytest.mark.parametrize("source", ["device", "host"])
+def test_mesh_shard_rows_lays_rows_out_on_the_mesh(source):
+    """Device arrays are laid out by one program with mesh output
+    shardings, host arrays are sliced per device; both give the same
+    (padded, masked) vDPU layout as the single-device grid."""
+    mesh = make_host_mesh(1, 1)
+    grid_m = PimGrid(n_vdpus=8, mesh=mesh, data_axes=("data",))
+    X = np.random.default_rng(3).normal(size=(100, 4)).astype(np.float32)
+    y = np.arange(100, dtype=np.int32)
+    src = (X, y) if source == "host" else (jnp.asarray(X), jnp.asarray(y))
+    d_m, n = grid_m.shard_rows(*src)
+    d_s, _ = make_cpu_grid(8).shard_rows(X, y)
+    assert n == 100
+    for k in ("X", "w", "y0"):
+        assert d_m[k].sharding == grid_m.data_sharding()
+        np.testing.assert_array_equal(np.asarray(d_m[k]),
+                                      np.asarray(d_s[k]))
+
+
 def test_fit_loop_runs_and_tracks_metrics():
     grid = make_cpu_grid(4)
     X = jax.random.normal(jax.random.PRNGKey(3), (64, 2))

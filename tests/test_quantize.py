@@ -87,6 +87,21 @@ class TestHybridDot:
         assert rel.max() < 1e-5
 
 
+class TestInt8Limbs:
+    @pytest.mark.parametrize("dtype,n_limbs", [(np.int8, 1), (np.uint8, 2),
+                                               (np.int16, 3)])
+    def test_signed_limbs_reconstruct_every_value(self, dtype, n_limbs):
+        """Every value of the dtype splits into true int8 limbs whose
+        weighted sum is exact (int16's 32640..32767 need the third)."""
+        info = np.iinfo(dtype)
+        x = np.arange(info.min, info.max + 1).astype(dtype)
+        limbs = qz.int8_limbs(jnp.asarray(x))
+        assert len(limbs) == n_limbs
+        assert all(l.dtype == jnp.int8 for _, l in limbs)
+        back = sum(int(w) * np.asarray(l, np.int64) for w, l in limbs)
+        np.testing.assert_array_equal(back, x.astype(np.int64))
+
+
 class TestErrorFeedback:
     def test_accumulated_error_stays_bounded(self):
         key = jax.random.PRNGKey(0)

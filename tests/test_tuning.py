@@ -4,6 +4,8 @@ prior/measured scale separation, the roofline cost model, the
 ``merge_plan="auto"`` spelling end to end on three workloads, and the
 offline replayability of recorded decision traces."""
 
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,7 @@ from repro.core import datasets, make_cpu_grid
 from repro.core.mlalgos import (make_linreg_step, train_linreg,
                                 train_multinomial, train_svm)
 from repro.distributed import merge_plan as mp
+from repro.roofline import hw
 from repro.distributed.compression import CompressionConfig
 from repro.tuning import (AutoTune, CostModel, Measurement, PlanChoice,
                           PlanController, auto_plan, cadence_ladder,
@@ -474,6 +477,24 @@ _FAST = dict(k_max=4, min_steps_to_explore=8, hold_rounds=2,
 _TRACE_KEYS = {"choices", "prior_margin", "prior_us_per_step",
                "measured_us_per_step", "cost_table", "decisions",
                "chosen", "cadence_trace"}
+
+
+class TestChipPeaks:
+    def test_cpu_models_the_v5e_target(self):
+        assert hw.peaks_for(jax.devices("cpu")[0]) is hw.TPU_V5E
+
+    def test_v5e_by_device_kind(self):
+        dev = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")
+        peaks = hw.peaks_for(dev)
+        assert (peaks.flops_bf16, peaks.ops_int8, peaks.hbm_bw) == (
+            197e12, 393e12, 819e9)
+
+    def test_unknown_tpu_is_an_error(self):
+        dev = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v99")
+        with pytest.raises(ValueError, match="TPU v99"):
+            hw.peaks_for(dev)
 
 
 class TestAutoFit:

@@ -51,6 +51,12 @@ CPU-centric bottleneck: the host dominates while the grid idles):
     lut_activation), so the body the scan compiles is the same code the
     TPU runs natively; ``engine="python"`` keeps the seed's per-step
     loop as the parity oracle.
+  * **profiler spans** — under ``jax.profiler`` each chunk leaves a
+    ``pim.dispatch`` host span around its runner call (cache lookup,
+    re-trace where it happens, enqueue) and a ``pim.history`` span
+    around its per-step unpacking, and each runner-cache miss one
+    ``pim.runner_build`` event: the trace says where the host holds the
+    device back.  Only the exact-plan scan loops carry them.
 
 DESIGN — merge cadence (``merge_every``)
 ----------------------------------------
@@ -368,7 +374,9 @@ class PimGrid:
 
             return jax.lax.scan(body, state, None, length=length)
 
-        mp.cache_put(self, key, runner, local_fn, update_fn)
+        # one profiler event per cache miss: the trace counts the misses
+        with jax.profiler.TraceAnnotation("pim.runner_build"):
+            mp.cache_put(self, key, runner, local_fn, update_fn)
         return runner
 
     def compiled_step(self, local_fn: Callable, update_fn: Callable):
@@ -576,12 +584,14 @@ class PimGrid:
             done = 0
             while done < steps:
                 length = min(scan_chunk, steps - done)
-                state, stacked = runner(state, data, length=length)
-                for i in range(length):
-                    metrics = jax.tree.map(lambda x, i=i: x[i], stacked)
-                    history.append(metrics)
-                    if callback is not None:
-                        callback(done + i, state, metrics)
+                with jax.profiler.TraceAnnotation("pim.dispatch"):
+                    state, stacked = runner(state, data, length=length)
+                with jax.profiler.TraceAnnotation("pim.history"):
+                    for i in range(length):
+                        metrics = jax.tree.map(lambda x, i=i: x[i], stacked)
+                        history.append(metrics)
+                        if callback is not None:
+                            callback(done + i, state, metrics)
                 done += length
             return state, history
 
@@ -595,28 +605,33 @@ class PimGrid:
         done_rounds = 0
         while done_rounds < rounds:
             length = min(scan_chunk, rounds - done_rounds)
-            state, stacked = runner(state, data, length=length)
-            for r in range(length):
-                for j in range(merge_every):
-                    metrics = jax.tree.map(
-                        lambda x, r=r, j=j: x[r, j], stacked)
-                    history.append(metrics)
-                    if callback is not None:
-                        callback((done_rounds + r) * merge_every + j,
-                                 state, metrics)
+            with jax.profiler.TraceAnnotation("pim.dispatch"):
+                state, stacked = runner(state, data, length=length)
+            with jax.profiler.TraceAnnotation("pim.history"):
+                for r in range(length):
+                    for j in range(merge_every):
+                        metrics = jax.tree.map(
+                            lambda x, r=r, j=j: x[r, j], stacked)
+                        history.append(metrics)
+                        if callback is not None:
+                            callback((done_rounds + r) * merge_every + j,
+                                     state, metrics)
             done_rounds += length
         if rem:
             # rem == 1 is served by the cadence-1 (merge-per-step)
             # runner, whose metric leaves are (1, ...) not (1, rem, ...)
             rem_runner = self.make_runner(local_fn, update_fn,
                                           merge_every=rem)
-            state, stacked = rem_runner(state, data, length=1)
-            for j in range(rem):
-                metrics = jax.tree.map(
-                    lambda x, j=j: x[0, j] if rem > 1 else x[0], stacked)
-                history.append(metrics)
-                if callback is not None:
-                    callback(rounds * merge_every + j, state, metrics)
+            with jax.profiler.TraceAnnotation("pim.dispatch"):
+                state, stacked = rem_runner(state, data, length=1)
+            with jax.profiler.TraceAnnotation("pim.history"):
+                for j in range(rem):
+                    metrics = jax.tree.map(
+                        lambda x, j=j: x[0, j] if rem > 1 else x[0],
+                        stacked)
+                    history.append(metrics)
+                    if callback is not None:
+                        callback(rounds * merge_every + j, state, metrics)
         return state, history
 
 

@@ -73,5 +73,7 @@ def fxp_matmul(a: jax.Array, b: jax.Array, *, block_m: int = 256,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="fxp_matmul",
+        metadata={"kernel": "fxp_matmul"},
     )(a, b)
     return out[:M, :N]

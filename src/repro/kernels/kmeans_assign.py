@@ -106,5 +106,7 @@ def kmeans_assign(x: jax.Array, centroids: jax.Array,
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
+        metadata={"kernel": "kmeans_assign"},
     )(x, centroids, w[:, None])
     return sums, counts[0], sse[0, 0]

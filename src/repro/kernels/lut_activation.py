@@ -92,5 +92,7 @@ def lut_activation(x: jax.Array, table: jax.Array, *, x_min: float,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         interpret=interpret,
+        name="lut_activation",
+        metadata={"kernel": "lut_activation"},
     )(x2, table)
     return out[:M, :N].reshape(orig_shape)

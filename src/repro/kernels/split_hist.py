@@ -94,6 +94,8 @@ def split_hist(node_idx: jax.Array, xbin: jax.Array, y: jax.Array,
         out_shape=jax.ShapeDtypeStruct((F, nbc), jnp.float32),
         scratch_shapes=[pltpu.VMEM((F, nbc), jnp.float32)],
         interpret=interpret,
+        name="split_hist",
+        metadata={"kernel": "split_hist"},
     )(node_idx.astype(jnp.int32)[None, :], xbin.astype(jnp.int32).T,
       y.astype(jnp.int32)[None, :], w[None, :])
     # (F, nodes*bins*classes) -> (nodes, F, bins, classes)

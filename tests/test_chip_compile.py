@@ -6,7 +6,9 @@ would raise (unsupported operand types, scalar VMEM stores, scoped-VMEM
 overflows).  Each test compiles one kernel with ``interpret=False`` at
 the per-vDPU shapes of the training path — 4,096 rows a lane — with the
 TPU block shapes from ``tuning.autotune``, and finds the kernel
-(``tpu_custom_call``) in the compiled program.  The quantized matvecs go
+(``tpu_custom_call``) in the compiled program, named by its
+``kernel_metadata`` (``"kernel":"<name>"``), which the profiler's trace
+carries in each kernel event's text.  The quantized matvecs go
 through ``dispatch.hybrid_matmul`` itself, so the limbs and orientation
 compiled here are the ones the training step sends.
 
@@ -85,6 +87,7 @@ def test_fxp_matmul_int8_limbs(one_chip, no_persistent_cache, gradient,
     text = _compile(matvec, _spec(one_chip, a_shape, data),
                     _spec(one_chip, (a_shape[1],), jnp.int16))
     assert "tpu_custom_call" in text
+    assert '"kernel":"fxp_matmul"' in text
 
 
 def test_kmeans_assign(one_chip, no_persistent_cache):
@@ -99,6 +102,7 @@ def test_kmeans_assign(one_chip, no_persistent_cache):
                     _spec(one_chip, (K, D), jnp.float32),
                     _spec(one_chip, (N,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert '"kernel":"kmeans_assign"' in text
 
 
 def test_split_hist_depth6(one_chip, no_persistent_cache):
@@ -120,6 +124,7 @@ def test_split_hist_depth6(one_chip, no_persistent_cache):
                     _spec(one_chip, (N,), jnp.int32),
                     _spec(one_chip, (N,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert '"kernel":"split_hist"' in text
 
 
 def test_lut_activation_logits(one_chip, no_persistent_cache):
@@ -132,3 +137,4 @@ def test_lut_activation_logits(one_chip, no_persistent_cache):
 
     text = _compile(sigmoid, _spec(one_chip, (ROWS,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert '"kernel":"lut_activation"' in text

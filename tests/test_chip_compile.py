@@ -10,7 +10,9 @@ TPU block shapes from ``tuning.autotune``, and finds the kernel
 ``kernel_metadata`` (``"kernel":"<name>"``), which the profiler's trace
 carries in each kernel event's text.  The quantized matvecs go
 through ``dispatch.hybrid_matmul`` itself, so the limbs and orientation
-compiled here are the ones the training step sends.
+compiled here are the ones the training step sends, and the LUT sigmoid
+is compiled vmapped over the benchmark cells' 2,048 vDPUs, as the step
+calls it.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time.
@@ -31,6 +33,7 @@ from repro.kernels import split_hist as _sh
 from repro.tuning import autotune as at
 
 ROWS, FEATURES = 4096, 32          # one vDPU's resident logreg block
+VDPUS = 2048                       # the logreg cells' vDPUs on one chip
 
 
 @pytest.fixture(scope="module")
@@ -127,14 +130,23 @@ def test_split_hist_depth6(one_chip, no_persistent_cache):
     assert '"kernel":"split_hist"' in text
 
 
-def test_lut_activation_logits(one_chip, no_persistent_cache):
-    """The LUT sigmoid over one vDPU's logit vector."""
+@pytest.mark.parametrize("rows", [8192, 64], ids=["gd", "sgd64"])
+def test_lut_activation_logits(one_chip, no_persistent_cache, rows):
+    """The LUT sigmoid over every vDPU's logit vector, vmapped over the
+    vDPUs as the training step does it: full-batch GD's 8,192 rows and
+    a 64-row SGD batch a vDPU.  The batching rule runs the kernel once
+    over the whole batch, so its name carries no ``vmap_``."""
     table = lut_mod.sigmoid_lut(n_entries=1024)
 
     def sigmoid(z):
         return _lut.lut_activation(z, table.table, x_min=table.x_min,
                                    x_max=table.x_max, interpret=False)
 
-    text = _compile(sigmoid, _spec(one_chip, (ROWS,), jnp.float32))
-    assert "tpu_custom_call" in text
+    text = _compile(jax.vmap(sigmoid),
+                    _spec(one_chip, (VDPUS, rows), jnp.float32))
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 1
     assert '"kernel":"lut_activation"' in text
+    name = kernels[0].split("=", 1)[0]
+    assert "lut_activation" in name and "vmap_" not in name

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import lut_activation, ops, ref
 from repro.kernels.kmeans_assign import kmeans_assign
 from repro.kernels.split_hist import split_hist
 from repro.core import lut as lutm
@@ -53,23 +53,102 @@ class TestFlashAttention:
             atol=3e-2, rtol=3e-2)
 
 
+# the per-vDPU logit vectors of the training step: gd's 8,192 rows, sgd's
+# 64-row batch over few vDPUs (flattened) and over a whole number of
+# 512-lane tiles of them (read transposed), and a shape that fills no tile
+VDPU_SHAPES = [(8, 8192), (16, 64), (512, 64), (3, 100)]
+LUT_TABLES = {
+    "sigmoid256": lambda: lutm.sigmoid_lut(256),
+    "sigmoid1024": lambda: lutm.sigmoid_lut(1024),
+    "gelu2048": lambda: lutm.gelu_lut(2048),
+    "tanh300": lambda: lutm.build_lut(np.tanh, -3.0, 3.0, 300),
+}
+STOCK_TABLES = {
+    "sigmoid256": lambda: lutm.sigmoid_lut(256),
+    "sigmoid1024": lambda: lutm.sigmoid_lut(1024),
+    "gelu512": lambda: lutm.gelu_lut(512),
+    "gelu2048": lambda: lutm.gelu_lut(2048),
+    "silu": lutm.silu_lut,
+    "tanh": lutm.tanh_lut,
+    "exp": lutm.exp_lut,
+}
+
+
+def _lut_probe(t, shape, dtype=jnp.float32):
+    """Random inputs over the table's domain with the edge cases written
+    over the first ones: both bounds, values beyond them, and the exact
+    half-step ties ``x_min + (k + 0.5) step`` of every entry."""
+    x = t.x_min + (t.x_max - t.x_min) * jax.random.uniform(
+        KEY, (int(np.prod(shape)),), jnp.float32, -0.25, 1.25)
+    ties = t.x_min + (jnp.arange(t.n_entries - 1) + 0.5) * t.step
+    edges = jnp.concatenate([
+        jnp.array([t.x_min, t.x_max, t.x_min - 1.0, t.x_max + 1.0,
+                   -1e6, 1e6]), ties]).astype(jnp.float32)
+    n = min(x.size, edges.size)
+    return x.at[:n].set(edges[:n]).reshape(shape).astype(dtype)
+
+
+def _vdpu_lut(t, how):
+    """The lookup of one vDPU's logit vector, vmapped over the vDPUs as
+    the training step does it, alone or inside a jitted scan."""
+    one = jax.vmap(lambda v: ops.lut_activation(v, t.table, x_min=t.x_min,
+                                                x_max=t.x_max))
+    if how == "vmap":
+        return one
+
+    @jax.jit
+    def scanned(z):
+        def body(c, s):
+            return c, one(z + s)
+        return jax.lax.scan(body, 0, jnp.zeros((2,), z.dtype))[1][1]
+    return scanned
+
+
+# XLA turns a division by a constant into a product with its reciprocal,
+# which moves exact half-step ties: kernel and reference are compared as
+# compiled programs
+_lut_ref = jax.jit(ref.lut_activation_ref, static_argnums=(2, 3))
+_lut_lookup = jax.jit(lutm.lut_lookup)
+
+
 class TestLutActivation:
-    @pytest.mark.parametrize("entries", [256, 1024])
-    @pytest.mark.parametrize("shape", [(256, 512), (128, 1024)])
-    def test_matches_ref(self, entries, shape):
-        t = lutm.sigmoid_lut(entries)
-        x = jax.random.normal(KEY, shape, jnp.float32) * 4
-        out = ops.lut_activation(x, t.table, x_min=t.x_min, x_max=t.x_max)
-        want = ref.lut_activation_ref(x, t.table, t.x_min, t.x_max)
+    @pytest.mark.parametrize("how", ["vmap", "scan"])
+    @pytest.mark.parametrize("shape", VDPU_SHAPES, ids=str)
+    @pytest.mark.parametrize("table", list(LUT_TABLES))
+    def test_matches_ref(self, table, shape, how):
+        t = LUT_TABLES[table]()
+        x = _lut_probe(t, shape)
+        out = _vdpu_lut(t, how)(x)
+        want = _lut_ref(x, t.table, t.x_min, t.x_max)
+        assert out.dtype == want.dtype
         np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
-    def test_matches_framework_lut(self):
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_matches_framework_lut(self, dtype):
         t = lutm.gelu_lut(512)
-        x = jax.random.normal(KEY, (256, 512)) * 3
+        x = _lut_probe(t, (256, 512), dtype)
         out = ops.lut_activation(x, t.table, x_min=t.x_min, x_max=t.x_max)
-        want = lutm.lut_lookup(t, x)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   atol=1e-6)
+        for want in (_lut_lookup(t, x),
+                     _lut_ref(x, t.table, t.x_min, t.x_max)):
+            assert out.dtype == want.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                          np.asarray(want, np.float32))
+
+    @pytest.mark.parametrize("table", list(STOCK_TABLES))
+    def test_table_splits_into_three_bf16_parts(self, table):
+        """The kernel's single bf16 pass is exact only if the table's
+        three bf16 parts add back to it: bit for bit, but for the sign
+        of a zero entry (the MXU's sum of a one-hot row drops it, as
+        the f32 matmul before did)."""
+        t = STOCK_TABLES[table]().table
+        hi, mid, lo = lut_activation.split_table(t)
+        assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+        back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)) \
+            + lo.astype(jnp.float32)
+        np.testing.assert_array_equal(
+            np.asarray(back).view(np.uint32),
+            np.asarray(t + 0.0).view(np.uint32))
 
 
 class TestFxpMatmul:

@@ -14,18 +14,26 @@ compiled here are the ones the training step sends, and the LUT sigmoid
 is compiled vmapped over the benchmark cells' 2,048 vDPUs, as the step
 calls it.
 
+The four-chip deployment's whole runner is compiled too: the int8 logistic
+regression that ``api.fit`` runs on a ``(1, 4)`` mesh of the described
+chips, kernels inside ``shard_map`` and the partials' all-reduce.
+
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library at a time.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.core import lut as lut_mod
+from repro.core import make_mesh_grid
+from repro.core.mlalgos import LogReg
 from repro.kernels import dispatch
 from repro.kernels import kmeans_assign as _km
 from repro.kernels import lut_activation as _lut
@@ -34,6 +42,8 @@ from repro.tuning import autotune as at
 
 ROWS, FEATURES = 4096, 32          # one vDPU's resident logreg block
 VDPUS = 2048                       # the logreg cells' vDPUs on one chip
+GD_ROWS = 8192                     # and each vDPU's rows
+CHIP_HBM_BYTES = 16 * 2 ** 30      # a v5e's memory
 
 
 @pytest.fixture(scope="module")
@@ -150,3 +160,43 @@ def test_lut_activation_logits(one_chip, no_persistent_cache, rows):
     assert '"kernel":"lut_activation"' in text
     name = kernels[0].split("=", 1)[0]
     assert "lut_activation" in name and "vmap_" not in name
+
+
+def test_mesh_logreg_runner(topo, no_persistent_cache, monkeypatch):
+    """The chunk runner ``api.fit`` builds for ``LogReg(precision="int8",
+    sigmoid="lut")`` on a ``(1, 4)`` mesh, at the shapes of the four-chip
+    deployment ``logreg-int8-16m-x4``: 2,048 vDPUs of 8,192 rows x 32
+    features a chip, sharded over
+    the mesh as ``PimGrid.shard_rows`` lays them out.  Both kernels
+    compile under Mosaic inside ``shard_map``, a chip's arguments and
+    temporaries fit its memory, and the partials meet in an
+    all-reduce."""
+    # the kernels and the runner's donation ask JAX's default backend,
+    # which is the CPU while a described chip compiles
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chips = len(topo.devices)
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, chips), ("pod", "data"),
+                axis_types=(AxisType.Auto,) * 2)
+    grid = make_mesh_grid(VDPUS * chips, mesh=mesh)
+    local_fn, update_fn, state0 = LogReg(
+        precision="int8", sigmoid="lut").spec_fns(
+            features=FEATURES, rows=grid.n_vdpus * GD_ROWS)
+    rows = grid.data_sharding()
+    shape = (grid.n_vdpus, GD_ROWS)
+    data = {"X": jax.ShapeDtypeStruct(shape + (FEATURES,), jnp.int8,
+                                      sharding=rows),
+            "y0": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows),
+            "w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)}
+    state = jax.ShapeDtypeStruct(state0.shape, state0.dtype,
+                                 sharding=grid.replicated_sharding())
+    compiled = grid.make_runner(local_fn, update_fn).lower(
+        state, data, length=32).compile()    # api.fit's scan_chunk
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert '"kernel":"fxp_matmul"' in text
+    assert '"kernel":"lut_activation"' in text
+    mem = compiled.memory_analysis()
+    chip_bytes = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert chip_bytes < CHIP_HBM_BYTES
+    assert re.search(r" all-reduce(-start)?\(", text)

@@ -127,7 +127,7 @@ def logreg_fit(grid, X, y, precision: str, s: Sizes, check_hlo: bool):
     if check_hlo:
         runner = grid.make_runner(prog.local_fn, prog.update_fn,
                                   merge_every=s.merge_every)
-        lowered = runner.lower(prog.state0, prog.data,
+        lowered = runner.lower(prog.state0, prog.data, prog.array_consts,
                                length=s.logreg_steps // s.merge_every)
         require_kernels(lowered.as_text(), f"logreg {precision} runner")
     res = prog.fit(steps=s.logreg_steps, merge_every=s.merge_every)

@@ -127,14 +127,16 @@ def minibatch_fns(local_fn: Callable, update_fn: Callable,
     the caller's state tree.  ``local_fn`` must follow the
     ``shard_rows`` slice convention (a dict with a per-row ``"w"``
     mask) — the schedule mask composes into ``"w"`` so padded schedule
-    slots contribute nothing, exactly like shard padding.
+    slots contribute nothing, exactly like shard padding.  A trailing
+    array operand (``PimGrid.fit``'s ``consts``) passes through to both
+    functions.
     """
     per, b = rows_per_vdpu, batch_size
     if not 1 <= b <= per:
         raise ValueError(
             f"batch_size must be in [1, rows_per_vdpu={per}], got {b}")
 
-    def sample_local_fn(carry, sl):
+    def sample_local_fn(carry, sl, *consts):
         state, t = carry
         # the counter is float32 for merge-averaging; it holds exact
         # integers (each step adds 1.0, each merge averages identical
@@ -143,16 +145,16 @@ def minibatch_fns(local_fn: Callable, update_fn: Callable,
                                   jnp.round(t).astype(jnp.int32))
         batch = {k: jnp.take(v, idx, axis=0) for k, v in sl.items()}
         batch["w"] = batch["w"] * mask
-        part = local_fn(state, batch)
+        part = local_fn(state, batch, *consts)
         # unbiased estimate of the full-partition statistic: E[scale *
         # sum over batch] = sum over partition (n_valid = b except on
         # the padded last batch of an epoch)
         scale = per / jnp.maximum(jnp.sum(mask), 1.0)
         return jax.tree.map(lambda x: x * scale, part)
 
-    def sample_update_fn(carry, merged):
+    def sample_update_fn(carry, merged, *consts):
         state, t = carry
-        new_state, metrics = update_fn(state, merged)
+        new_state, metrics = update_fn(state, merged, *consts)
         return (new_state, t + 1.0), metrics
 
     wrapped0 = (init_state, jnp.zeros((), jnp.float32))

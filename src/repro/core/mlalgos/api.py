@@ -22,12 +22,15 @@ the dry-run — is generic over the protocol: a new estimator is a
 ~100-line plugin (``svm.py`` and ``multinomial.py`` are the proof).
 
 ``bind`` assembles a :class:`Program`: the closures ``PimGrid.fit``
-consumes, built once so repeated fits hit the engine's signature-keyed
-compile cache (the workload instance and the trace-time constants ride
+consumes.  Every ``api.fit`` binds anew (``prepare`` runs in full:
+quantization, placement, scales), and the new closures still hit the
+engine's signature-keyed compile cache: the workload instance and the
+hashable constants (row and feature counts, the sigmoid function) ride
 in the closures' default args, which ``merge_plan.fn_signature`` keys
-by value for hashable frozen dataclasses and primitives — two equal
-estimators share a runner, two different hyperparameter sets never
-collide).
+by value, and the array constants (quantization scales) are a runtime
+operand of the runner, not a capture.  Two equal estimators on data of
+the same shapes share a runner whatever their scales; two different
+hyperparameter sets never collide.
 
 DESIGN — the minibatch axis (``fit(batch_size=b)``)
 ---------------------------------------------------
@@ -67,6 +70,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import minibatch as mb
 from repro.core.pim import PimGrid
@@ -141,14 +145,17 @@ class Workload:
     and implement the five protocol members plus ``prepare``.
 
     ``consts`` is the dict ``prepare`` returns next to the resident
-    data: the *trace-time constants* the step functions read (row
-    count, feature count, quantization scales).  It is captured in the
-    assembled closures — primitives key the compile cache by value,
-    arrays by identity (the quantized paths re-quantize per bind, so
-    their keys never repeat, exactly like the pre-protocol closures).
-    Keys starting with ``"_"`` are bind-time-only (read by
-    ``init_state``, excluded from the step closures and their cache
-    keys — kmeans' initial centroids live there).
+    data: the constants the step functions read (row count, feature
+    count, quantization scales).  :meth:`Program.assemble` splits it:
+    array values become the runner's ``consts`` operand, everything
+    else is captured in the assembled closures and keys the compile
+    cache by value, so it must be hashable (primitives, frozen
+    dataclasses, functions with a stable identity such as
+    ``logreg.make_sigmoid``'s).  ``local_step`` and ``update`` see the
+    two parts merged into one dict.  Keys starting with ``"_"`` are
+    bind-time-only (read by ``init_state``, excluded from the step
+    functions and their cache keys — kmeans' initial centroids live
+    there).
     """
 
     name: str = "workload"
@@ -279,11 +286,11 @@ class FitResult:
 @dataclasses.dataclass
 class Program:
     """A workload bound to a grid and a resident dataset: the stable
-    ``(local_fn, update_fn, init_state)`` triple plus the placement.
-    Benchmarks bind once and sweep fit options against stable
-    compile-cache keys; ``train_*`` binds per call (same keys when the
-    hyperparameters and dataset scales allow — see the module
-    docstring)."""
+    ``(local_fn, update_fn, init_state)`` triple, the array constants
+    the triple's functions take as their third argument
+    (``array_consts``, ``PimGrid.fit``'s ``consts``) and the placement.
+    Every bind of an equal estimator on data of the same shapes gets
+    equal compile-cache keys (see the module docstring)."""
 
     workload: Workload
     grid: PimGrid
@@ -293,48 +300,49 @@ class Program:
     local_fn: Callable
     update_fn: Callable
     state0: Any
-    _mb_cache: dict = dataclasses.field(default_factory=dict, repr=False)
+    array_consts: dict = dataclasses.field(default_factory=dict)
 
     @classmethod
     def assemble(cls, workload: Workload, grid: PimGrid, data, n,
                  consts: dict) -> "Program":
-        # hyperparameters and constants ride in the default args: the
-        # compile cache keys them by value (hashable dataclasses,
-        # primitives) or identity (arrays) — see merge_plan.fn_signature.
-        # Keys starting with "_" are bind-time-only (init_state inputs
-        # like kmeans' initial centroids) and stay out of the step
-        # closures, so they never poison an otherwise value-stable key.
+        # Hyperparameters and hashable constants ride in the default
+        # args, which the compile cache keys by value
+        # (merge_plan.fn_signature); array constants are the runner's
+        # operand, merged back in at trace time, so new scales never
+        # change the key.  Keys starting with "_" are bind-time-only
+        # (init_state inputs like kmeans' initial centroids) and stay
+        # out of the step functions.
         step_consts = {k: v for k, v in consts.items()
                        if not k.startswith("_")}
+        arrays = {k: v for k, v in step_consts.items()
+                  if isinstance(v, (jax.Array, np.ndarray))}
+        static = {k: v for k, v in step_consts.items() if k not in arrays}
 
-        def local_fn(state, sl, _w=workload, _c=step_consts):
-            return _w.local_step(_c, state, sl)
+        def local_fn(state, sl, arrays, _w=workload, _c=static):
+            return _w.local_step({**_c, **arrays}, state, sl)
 
-        def update_fn(state, merged, _w=workload, _c=step_consts):
-            return _w.update(_c, state, merged)
+        def update_fn(state, merged, arrays, _w=workload, _c=static):
+            return _w.update({**_c, **arrays}, state, merged)
 
         return cls(workload=workload, grid=grid, data=data, n=n,
                    consts=consts, local_fn=local_fn, update_fn=update_fn,
-                   state0=workload.init_state(consts))
+                   state0=workload.init_state(consts),
+                   array_consts=arrays)
 
     @property
     def rows_per_vdpu(self) -> int:
         return int(self.data["w"].shape[1])
 
     def _triple(self, batch_size: Optional[int], sample_seed: int):
-        """The engine triple, minibatch-wrapped when asked.  Wrapped
-        triples are cached per ``(batch_size, seed)`` so repeated fits
-        keep stable compile-cache keys."""
+        """The engine triple, minibatch-wrapped when asked.  A wrapper
+        keys the compile cache by the signature of the functions it
+        wraps, so the wrappers of repeated fits share runners."""
         if batch_size is None:
             return self.local_fn, self.update_fn, self.state0, None
-        key = (batch_size, sample_seed)
-        if key not in self._mb_cache:
-            lf, uf, s0, unwrap = mb.minibatch_fns(
-                self.local_fn, self.update_fn, self.state0,
-                rows_per_vdpu=self.rows_per_vdpu, batch_size=batch_size,
-                seed=sample_seed)
-            self._mb_cache[key] = (lf, uf, s0, unwrap)
-        return self._mb_cache[key]
+        return mb.minibatch_fns(
+            self.local_fn, self.update_fn, self.state0,
+            rows_per_vdpu=self.rows_per_vdpu, batch_size=batch_size,
+            seed=sample_seed)
 
     @partial(jax.profiler.annotate_function, name="pim.fit")
     def fit(self, *, steps: int, batch_size: Optional[int] = None,
@@ -383,7 +391,8 @@ class Program:
             init_state=state0, local_fn=local_fn, update_fn=update_fn,
             data=self.data, steps=steps, engine=engine,
             scan_chunk=scan_chunk, merge_plan=plan,
-            merge_state=merge_state, callback=cb)
+            merge_state=merge_state, callback=cb,
+            consts=self.array_consts)
         if unwrap is not None:
             state = unwrap(state)
         return FitResult(state=state, history=history,
@@ -399,14 +408,14 @@ class Program:
         checkpoint/replay restores the schedule position for free."""
         local_fn, update_fn, state0, _ = self._triple(
             batch_size, sample_seed)
-        grid, data = self.grid, self.data
+        grid, data, consts = self.grid, self.data, self.array_consts
 
         @jax.jit
-        def step(state, batch):
-            merged = grid.map_reduce(local_fn, state, data)
-            return update_fn(state, merged)
+        def step_c(state, batch, consts):
+            merged = grid.map_reduce(local_fn, state, data, consts)
+            return update_fn(state, merged, consts)
 
-        return step, state0
+        return partial(step_c, consts=consts), state0
 
     def round_fn(self, k: int, *, batch_size: Optional[int] = None,
                  sample_seed: int = 0):
@@ -424,14 +433,14 @@ class Program:
 
         local_fn, update_fn, state0, _ = self._triple(
             batch_size, sample_seed)
-        grid, data = self.grid, self.data
+        grid, data, consts = self.grid, self.data, self.array_consts
 
         @jax.jit
-        def round(state, batch):
+        def round_c(state, batch, consts):
             return mp.cadence_round(grid, local_fn, update_fn, k,
-                                    state, data)
+                                    state, data, consts)
 
-        return round, state0
+        return partial(round_c, consts=consts), state0
 
 
 @dataclasses.dataclass
@@ -482,14 +491,14 @@ class StreamProgram(Program):
             batch_size, sample_seed)
         slf = (local_fn if self.data.exact_full
                else make_scaled_local(local_fn))
-        grid = self.grid
+        grid, consts = self.grid, self.array_consts
 
         @jax.jit
-        def step(state, batch):
-            merged = grid.map_reduce(slf, state, batch)
-            return update_fn(state, merged)
+        def step_c(state, batch, consts):
+            merged = grid.map_reduce(slf, state, batch, consts)
+            return update_fn(state, merged, consts)
 
-        return step, state0
+        return partial(step_c, consts=consts), state0
 
     def round_fn(self, k: int, *, batch_size: Optional[int] = None,
                  sample_seed: int = 0):
@@ -502,14 +511,14 @@ class StreamProgram(Program):
             batch_size, sample_seed)
         slf = (local_fn if self.data.exact_full
                else make_scaled_local(local_fn))
-        grid = self.grid
+        grid, consts = self.grid, self.array_consts
 
         @jax.jit
-        def round(state, batch):
+        def round_c(state, batch, consts):
             return mp.cadence_round(grid, slf, update_fn, k,
-                                    state, batch)
+                                    state, batch, consts)
 
-        return round, state0
+        return partial(round_c, consts=consts), state0
 
 
 # ---------------------------------------------------------------------------

@@ -165,16 +165,22 @@ def make_linreg_step(grid: PimGrid, X: jax.Array, y: jax.Array, *,
     """Build the grid-engine pieces for one linreg problem.
 
     Returns ``(data, n, local_fn, update_fn, w0)`` ready for
-    ``grid.fit`` — the bound :class:`LinReg` program's triple.  Exposed
+    ``grid.fit`` — the bound :class:`LinReg` program's triple, with its
+    array constants bound into two-argument functions.  Exposed
     separately from :func:`train_linreg` so benchmarks can build the
     closures *once* and sweep ``fit`` options (engine, cadence) against
     stable compile-cache keys — re-building per timed call would
-    measure retracing, not step rate (the quantized paths capture fresh
-    scale arrays, so their keys never repeat across builds).
+    measure retracing, not step rate (the quantized paths' functions
+    capture their scale arrays here, so their keys never repeat across
+    builds; ``api.fit`` passes the scales as an operand instead).
     """
+    from repro.distributed.merge_plan import bind_consts
+
     program = LinReg(lr=lr, precision=precision, l2=l2).bind(grid, X, y)
-    return (program.data, program.n, program.local_fn,
-            program.update_fn, program.state0)
+    consts = program.array_consts
+    return (program.data, program.n,
+            bind_consts(program.local_fn, consts),
+            bind_consts(program.update_fn, consts), program.state0)
 
 
 def train_linreg(grid: PimGrid, X: jax.Array, y: jax.Array, *,

@@ -18,6 +18,7 @@ thin wrapper over the protocol.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import jax
@@ -42,11 +43,22 @@ class LogRegResult:
 
 
 def make_sigmoid(kind: Sigmoid, n_entries: int = 1024):
+    """The sigmoid variant ``kind``: the same function object for the
+    same arguments, so a step function that captures it keeps its
+    compile-cache key from one bind to the next."""
+    return _sigmoid(kind, int(n_entries))
+
+
+@functools.cache
+def _sigmoid(kind: Sigmoid, n_entries: int):
     if kind == "exact":
         return jax.nn.sigmoid
     if kind == "taylor":
         return lut_mod.taylor_sigmoid
-    table = lut_mod.sigmoid_lut(n_entries=n_entries)
+    # a concrete table even when first asked for inside a trace (the
+    # serving runner jits predict): the memo outlives every trace
+    with jax.ensure_compile_time_eval():
+        table = lut_mod.sigmoid_lut(n_entries=n_entries)
     if kind == "lut":
         # nearest-entry LUT routes through the lut_activation Pallas
         # kernel (one-hot @ table on the MXU)
@@ -164,12 +176,15 @@ class LogReg(api.Workload):
     def spec_fns(self, *, features: int, rows: int):
         """Spec-level engine fns for lowering without resident data
         (``launch.dryrun_pim``): unit quantization scales, int8
-        resident dataset, the configured sigmoid."""
+        resident dataset, the configured sigmoid.  Returns ``(local_fn,
+        update_fn, state0, consts)``: the functions take ``consts``, the
+        array constants, as their third argument, as in ``api.fit``."""
         consts = {"n": rows, "d": features,
                   "sig": make_sigmoid(self.sigmoid, self.lut_entries),
                   "x_scale": jnp.ones((1, features), jnp.float32)}
         program = api.Program.assemble(self, None, None, rows, consts)
-        return program.local_fn, program.update_fn, program.state0
+        return (program.local_fn, program.update_fn, program.state0,
+                program.array_consts)
 
 
 def train_logreg(grid: PimGrid, X: jax.Array, y: jax.Array, *,

@@ -21,6 +21,7 @@ binary workloads.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal
 
 import jax
@@ -46,11 +47,19 @@ class MultinomialResult:
 
 def make_softmax(kind: Softmax, n_entries: int = 1024):
     """Row-wise softmax over shifted logits; the ``lut`` variant
-    evaluates exp via the one-sided table on the Pallas LUT kernel."""
+    evaluates exp via the one-sided table on the Pallas LUT kernel.
+    The same function object for the same arguments, as
+    ``logreg.make_sigmoid``."""
+    return _softmax(kind, int(n_entries))
+
+
+@functools.cache
+def _softmax(kind: Softmax, n_entries: int):
     if kind == "exact":
         return lambda z: jax.nn.softmax(z, axis=-1)
     if kind == "lut":
-        table = lut_mod.exp_lut(n_entries=n_entries)
+        with jax.ensure_compile_time_eval():
+            table = lut_mod.exp_lut(n_entries=n_entries)
 
         def lut_softmax(z):
             shifted = z - jax.lax.stop_gradient(
@@ -171,12 +180,15 @@ class MultinomialLogReg(api.Workload):
 
     def spec_fns(self, *, features: int, rows: int):
         """Spec-level engine fns for ``launch.dryrun_pim`` (unit
-        quantization scales; no resident data materialized)."""
+        quantization scales; no resident data materialized):
+        ``(local_fn, update_fn, state0, consts)``, as
+        ``LogReg.spec_fns``."""
         consts = {"n": rows, "d": features,
                   "sm": make_softmax(self.softmax, self.lut_entries),
                   "x_scale": jnp.ones((1, features), jnp.float32)}
         program = api.Program.assemble(self, None, None, rows, consts)
-        return program.local_fn, program.update_fn, program.state0
+        return (program.local_fn, program.update_fn, program.state0,
+                program.array_consts)
 
 
 def train_multinomial(grid: PimGrid, X: jax.Array, y: jax.Array, *,

@@ -143,11 +143,14 @@ class LinearSVM(api.Workload):
 
     def spec_fns(self, *, features: int, rows: int):
         """Spec-level engine fns for ``launch.dryrun_pim`` (unit
-        quantization scales; no resident data materialized)."""
+        quantization scales; no resident data materialized):
+        ``(local_fn, update_fn, state0, consts)``, as
+        ``LogReg.spec_fns``."""
         consts = {"n": rows, "d": features,
                   "x_scale": jnp.ones((1, features), jnp.float32)}
         program = api.Program.assemble(self, None, None, rows, consts)
-        return program.local_fn, program.update_fn, program.state0
+        return (program.local_fn, program.update_fn, program.state0,
+                program.array_consts)
 
 
 def train_svm(grid: PimGrid, X: jax.Array, y: jax.Array, *,

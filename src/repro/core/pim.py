@@ -43,9 +43,11 @@ CPU-centric bottleneck: the host dominates while the grid idles):
     callbacks is live carry: its buffers are consumed by the next
     chunk's dispatch, so callbacks that retain state must copy it.
   * **compile cache** — the jitted chunk runner is cached on the grid
-    keyed by ``(local_fn, update_fn)``; repeated ``fit`` calls with the
-    same functions never retrace (at most two traces per pair: the full
-    chunk and the remainder chunk).
+    keyed by the signatures of ``(local_fn, update_fn)``; the fit's
+    array constants (``consts``) are a runner operand, so repeated
+    ``fit`` calls with equal functions never retrace, whatever scales
+    they carry (at most two traces per pair: the full chunk and the
+    remainder chunk).
   * **kernel dispatch** — the mlalgos' inner loops route through
     ``repro.kernels.dispatch`` (fxp_matmul / kmeans_assign / split_hist /
     lut_activation), so the body the scan compiles is the same code the
@@ -221,14 +223,16 @@ class PimGrid:
 
     # -- the core primitive ---------------------------------------------
 
-    def map_reduce(self, local_fn: Callable[[Any, Any], Any],
-                   model: Any, data: Any) -> Any:
+    def map_reduce(self, local_fn: Callable, model: Any, data: Any,
+                   consts: Any = None) -> Any:
         """partial = local_fn(model, per_vdpu_slice); return Σ partial.
 
         ``local_fn`` sees one vDPU's resident slice (no leading axis) and
         returns a pytree of summable statistics.  The reduction is the
         paper's host merge: vmapped-tasklet sum -> intra-pod psum -> pod
-        psum.
+        psum.  Given ``consts``, the fit's array constants, it is called
+        as ``local_fn(model, slice, consts)``; on a mesh ``consts`` enters
+        ``shard_map`` replicated, like ``model``.
 
         Example — a masked global sum (padding rows carry ``w == 0`` and
         contribute nothing):
@@ -243,13 +247,17 @@ class PimGrid:
         >>> float(out["s"])
         28.0
         """
+        from repro.distributed.merge_plan import bind_consts
+
         if self.mesh is None:
-            return _tree_sum_leading(jax.vmap(lambda d: local_fn(model, d))(data))
+            lf = bind_consts(local_fn, consts)
+            return _tree_sum_leading(jax.vmap(lambda d: lf(model, d))(data))
 
         axes = tuple(self.data_axes)
 
-        def shard_body(model, data):
-            part = _tree_sum_leading(jax.vmap(lambda d: local_fn(model, d))(data))
+        def shard_body(model, consts, data):
+            lf = bind_consts(local_fn, consts)
+            part = _tree_sum_leading(jax.vmap(lambda d: lf(model, d))(data))
             # Hierarchical merge: fast axes first (ICI), slow axis last
             # (the "host" hop). Mathematically one psum; structurally two
             # collectives with different replica groups (see roofline).
@@ -261,9 +269,9 @@ class PimGrid:
         data_specs = jax.tree.map(lambda _: P(axes), data)
         return shard_map(
             shard_body, mesh=self.mesh,
-            in_specs=(P(), data_specs), out_specs=P(),
+            in_specs=(P(), P(), data_specs), out_specs=P(),
             check_vma=False,
-        )(model, data)
+        )(model, consts, data)
 
     # -- merge-plan delegation ------------------------------------------
     #
@@ -293,32 +301,39 @@ class PimGrid:
                     merge_every: int = 1):
         """The cached jitted chunk runner for ``(local_fn, update_fn)``.
 
-        ``runner(state, data, length=L)`` scans L merge rounds and
-        returns ``(state, stacked_metrics)``.  At ``merge_every=1`` a
-        round is one merge->update step and metric leaves come back
-        shaped ``(L, ...)``; at cadence ``k > 1`` a round is ``k``
-        vDPU-local steps plus one state merge and metric leaves are
-        ``(L, k, ...)``.  ``length`` is static, so a fit sees at most
-        two traces per cadence (chunk + remainder).
+        ``runner(state, data, consts=None, length=L)`` scans L merge
+        rounds and returns ``(state, stacked_metrics)``.  At
+        ``merge_every=1`` a round is one merge->update step and metric
+        leaves come back shaped ``(L, ...)``; at cadence ``k > 1`` a
+        round is ``k`` vDPU-local steps plus one state merge and metric
+        leaves are ``(L, k, ...)``.  ``length`` is static, so a fit sees
+        at most two traces per cadence (chunk + remainder).  ``consts``
+        is the fit's array constants (quantization scales), a runtime
+        operand handed to the step functions as their third argument
+        (``merge_plan.bind_consts``); ``None`` for two-argument step
+        functions.
 
         Compile-cache keying rules: the runner is cached on the grid
         keyed by
 
           * the *signatures* of ``local_fn``/``update_fn`` — code object
-            plus captured closure-cell and default-arg values (primitives
-            by value, arrays/objects by identity).  ``train_*`` re-creates
-            its closures each call; same code + same captured values
-            still hit the cache, while a changed hyperparameter
-            (``lr=lr`` closure or default binding) forces a new trace,
+            plus captured closure-cell and default-arg values
+            (primitives, hashable frozen dataclasses and captured
+            functions by value, arrays and other objects by identity;
+            ``merge_plan.fn_signature``).  ``api.fit`` binds new
+            closures each call; same code + same captured values hit
+            the cache, while a changed hyperparameter forces a new
+            trace.  Array constants are operands, not captures, so new
+            scales on the same shapes reuse the runner,
           * the trace-time ``kernels.dispatch`` flag — a runner traced
             with Pallas kernels on never serves a ``use_kernels(False)``
             fit,
           * ``merge_every`` — each cadence compiles its own round body.
 
         The cache is a bounded LRU (``merge_plan._FIT_CACHE_MAX``
-        entries): paths whose closures capture fresh arrays per call
-        (the quantized mlalgos) never repeat a key and would otherwise
-        pin compiled executables forever.
+        entries): step functions that capture arrays key by the arrays'
+        identity, never repeat a key, and would otherwise pin compiled
+        executables forever.
 
         Example — repeated requests reuse the runner, a different
         cadence gets its own:
@@ -360,17 +375,20 @@ class PimGrid:
 
         @partial(jax.jit, static_argnames=("length",),
                  donate_argnums=donate)
-        def runner(state, data, *, length: int):
+        def runner(state, data, consts=None, *, length: int):
             if merge_every == 1:
                 # the PR 1 merge-per-step body, unchanged — cadence 1 is
                 # bit-exact with the pre-cadence engine by construction
+                update = mp.bind_consts(update_fn, consts)
+
                 def body(state, _):
-                    merged = self.map_reduce(local_fn, state, data)
-                    return update_fn(state, merged)
+                    merged = self.map_reduce(local_fn, state, data, consts)
+                    return update(state, merged)
             else:
                 def body(state, _):
                     return mp.cadence_round(self, local_fn, update_fn,
-                                            merge_every, state, data)
+                                            merge_every, state, data,
+                                            consts)
 
             return jax.lax.scan(body, state, None, length=length)
 
@@ -393,12 +411,20 @@ class PimGrid:
             scan_chunk: int = 32, engine: str = "scan",
             merge_every: int = 1, overlap_merge: bool = False,
             merge_compression=None, merge_state: dict | None = None,
-            merge_plan=None):
+            merge_plan=None, consts: Any = None):
         """Run the paper's iterative loop: local partials -> merge -> update.
 
         ``update_fn(state, merged) -> (state, metrics)`` runs "on the host"
         (replicated).  Returns ``(state, [metrics per step])`` — always
         one history entry per *local* step, whatever the cadence.
+
+        ``consts`` is the fit's array constants (quantization scales):
+        given, the step functions take it as a third argument,
+        ``local_fn(state, slice, consts)`` and ``update_fn(state,
+        merged, consts)``, and every runner receives it as an operand,
+        so a new fit with new constants of the same shapes reuses the
+        compiled runner (``make_runner``).  Under an armed fault plan
+        ``resilience.drive_fit`` gets the functions with it bound in.
 
         ``engine="scan"`` (default) compiles the loop as chunked
         ``lax.scan`` (see DESIGN in the module docstring);
@@ -492,7 +518,7 @@ class PimGrid:
                 self, data, init_state=init_state, local_fn=local_fn,
                 update_fn=update_fn, steps=steps, plan=plan,
                 merge_state=merge_state, callback=callback,
-                scan_chunk=scan_chunk, engine=engine)
+                scan_chunk=scan_chunk, engine=engine, consts=consts)
 
         # fault-injection hook (repro.resilience): when a FaultPlan is
         # armed, non-controller fits run under the resilient driver —
@@ -504,8 +530,10 @@ class PimGrid:
 
             fplan, recovery, ckpt, ckpt_every = ctx
             state, history, _report = _resilient.drive_fit(
-                self, init_state=init_state, local_fn=local_fn,
-                update_fn=update_fn, data=data, steps=steps,
+                self, init_state=init_state,
+                local_fn=mp.bind_consts(local_fn, consts),
+                update_fn=mp.bind_consts(update_fn, consts),
+                data=data, steps=steps,
                 plan=plan, fault_plan=fplan, recovery=recovery,
                 ckpt=ckpt, ckpt_every_rounds=ckpt_every,
                 scan_chunk=scan_chunk, callback=callback,
@@ -517,21 +545,21 @@ class PimGrid:
                 self, plan, init_state=init_state, local_fn=local_fn,
                 update_fn=update_fn, data=data, steps=steps,
                 callback=callback, scan_chunk=scan_chunk, engine=engine,
-                merge_state=merge_state)
+                merge_state=merge_state, consts=consts)
 
         merge_every = plan.cadence
 
         if engine == "python":
             if merge_every == 1:
                 @jax.jit
-                def one_step(state, data):
-                    merged = self.map_reduce(local_fn, state, data)
-                    return update_fn(state, merged)
+                def one_step(state, data, consts):
+                    merged = self.map_reduce(local_fn, state, data, consts)
+                    return mp.bind_consts(update_fn, consts)(state, merged)
 
                 history = []
                 state = init_state
                 for step in range(steps):
-                    state, metrics = one_step(state, data)
+                    state, metrics = one_step(state, data, consts)
                     history.append(metrics)
                     if callback is not None:
                         callback(step, state, metrics)
@@ -551,16 +579,16 @@ class PimGrid:
                 fn = round_fns.get(k)
                 if fn is None:
                     if k == 1:
-                        def fn(st, d):
-                            merged = self.map_reduce(local_fn, st, d)
-                            return update_fn(st, merged)
+                        def fn(st, d, c):
+                            merged = self.map_reduce(local_fn, st, d, c)
+                            return mp.bind_consts(update_fn, c)(st, merged)
                         fn = jax.jit(fn)
                     else:
                         fn = jax.jit(
-                            lambda st, d, _k=k: mp.cadence_round(
-                                self, local_fn, update_fn, _k, st, d))
+                            lambda st, d, c, _k=k: mp.cadence_round(
+                                self, local_fn, update_fn, _k, st, d, c))
                     round_fns[k] = fn
-                state, stacked = fn(state, data)
+                state, stacked = fn(state, data, consts)
                 for j in range(k):
                     metrics = jax.tree.map(
                         lambda x, j=j: x[j] if k > 1 else x, stacked)
@@ -585,7 +613,8 @@ class PimGrid:
             while done < steps:
                 length = min(scan_chunk, steps - done)
                 with jax.profiler.TraceAnnotation("pim.dispatch"):
-                    state, stacked = runner(state, data, length=length)
+                    state, stacked = runner(state, data, consts,
+                                            length=length)
                 with jax.profiler.TraceAnnotation("pim.history"):
                     for i in range(length):
                         metrics = jax.tree.map(lambda x, i=i: x[i], stacked)
@@ -606,7 +635,8 @@ class PimGrid:
         while done_rounds < rounds:
             length = min(scan_chunk, rounds - done_rounds)
             with jax.profiler.TraceAnnotation("pim.dispatch"):
-                state, stacked = runner(state, data, length=length)
+                state, stacked = runner(state, data, consts,
+                                        length=length)
             with jax.profiler.TraceAnnotation("pim.history"):
                 for r in range(length):
                     for j in range(merge_every):
@@ -623,7 +653,8 @@ class PimGrid:
             rem_runner = self.make_runner(local_fn, update_fn,
                                           merge_every=rem)
             with jax.profiler.TraceAnnotation("pim.dispatch"):
-                state, stacked = rem_runner(state, data, length=1)
+                state, stacked = rem_runner(state, data, consts,
+                                            length=1)
             with jax.profiler.TraceAnnotation("pim.history"):
                 for j in range(rem):
                     metrics = jax.tree.map(

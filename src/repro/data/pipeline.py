@@ -550,36 +550,24 @@ def _release_window(d: Optional[dict]) -> None:
                 pass
 
 
-_SCALED_LOCAL_CACHE: dict = {}
-_SCALED_LOCAL_CACHE_MAX = 256
-
-
 def make_scaled_local(local_fn: Callable) -> Callable:
     """Wrap an engine ``local_fn`` for streaming windows: strip the
     rotation's ``"scale"`` leaf from the slice and apply it to the
     partial-statistics tree — the exact multiply the on-device sampler
     performs, hoisted to the window level.
 
-    Wrappers are memoized by ``fn_signature(local_fn)``: every bind of
-    an equal workload configuration returns the SAME wrapper object, so
-    the grid's compile cache (which keys non-primitive closure values
-    by identity) hits across windows AND across fits — rebinding a
-    streaming program never retraces."""
-    from repro.distributed import merge_plan as _mp
-    key = _mp.fn_signature(local_fn)
-    got = _SCALED_LOCAL_CACHE.get(key)
-    if got is not None:
-        return got
-
-    def streaming_local_fn(state, sl, _lf=local_fn):
+    ``merge_plan.fn_signature`` keys the wrapper by ``local_fn``'s own
+    signature, so the wrappers of equal workload configurations share
+    the grid's compiled runners across windows AND across fits —
+    rebinding a streaming program never retraces.  A trailing array
+    operand (``PimGrid.fit``'s ``consts``) passes through to
+    ``local_fn``."""
+    def streaming_local_fn(state, sl, *consts, _lf=local_fn):
         scale = sl["scale"]
         rows = {k: v for k, v in sl.items() if k != "scale"}
-        part = _lf(state, rows)
+        part = _lf(state, rows, *consts)
         return jax.tree.map(lambda x: x * scale, part)
 
-    _SCALED_LOCAL_CACHE[key] = streaming_local_fn
-    while len(_SCALED_LOCAL_CACHE) > _SCALED_LOCAL_CACHE_MAX:
-        _SCALED_LOCAL_CACHE.pop(next(iter(_SCALED_LOCAL_CACHE)))
     return streaming_local_fn
 
 
@@ -587,7 +575,8 @@ def run_streaming_fit(grid, rotation: PartitionRotation, *, init_state,
                       local_fn, update_fn, steps: int, plan,
                       merge_state: Optional[dict] = None,
                       callback: Optional[Callable] = None,
-                      scan_chunk: int = 32, engine: str = "scan"):
+                      scan_chunk: int = 32, engine: str = "scan",
+                      consts=None):
     """The out-of-core training driver: rotate resident partitions
     through ``PimGrid.fit`` while the prefetcher double-buffers the
     next window's gather + H2D behind the current window's compute.
@@ -643,7 +632,8 @@ def run_streaming_fit(grid, rotation: PartitionRotation, *, init_state,
                 init_state=state, local_fn=scaled_lf,
                 update_fn=update_fn, data=data, steps=k,
                 merge_plan=plan, merge_state=merge_state,
-                engine=engine, scan_chunk=scan_chunk, callback=cb)
+                engine=engine, scan_chunk=scan_chunk, callback=cb,
+                consts=consts)
             jax.block_until_ready(state)
             history.extend(h)
             done += k

@@ -128,6 +128,7 @@ True
 from __future__ import annotations
 
 import dataclasses
+import types
 import warnings
 from functools import partial
 from typing import Any, Callable, Optional
@@ -145,6 +146,8 @@ from repro.distributed.overlap import double_buffered_body
 
 
 _FIT_CACHE_MAX = 64
+# how deep fn_signature follows captured functions before keying by id
+_SIGNATURE_DEPTH = 8
 
 
 class MergeFallbackWarning(UserWarning):
@@ -172,24 +175,34 @@ def donating_backend() -> bool:
     return jax.default_backend() in ("gpu", "tpu")
 
 
-def fn_signature(fn) -> tuple:
+def fn_signature(fn, _depth: int = 0) -> tuple:
     """Cache key for a step function: code identity + closure contents.
 
     ``train_*`` re-creates its closures on every call, so keying the
     compile cache on function *identity* would never hit.  Two closures
-    with the same code object and the same captured values (primitives by
-    value, everything else by object identity) trace to the same jaxpr,
-    so they can share a compiled runner.  Callers must keep the closure
-    alive while the key is in use (the cache stores the functions next to
-    the runner) so ``id()`` keys cannot be recycled.
+    with the same code object and the same captured values trace to the
+    same jaxpr, so they can share a compiled runner.  Captured values
+    key by value where they can:
 
-    Containers (tuples, string-keyed dicts) and *hashable* frozen
-    dataclasses key recursively / by value — the Workload layer
-    (``core.mlalgos.api``) captures the estimator instance and its
-    trace-time constants in default args, and two equal estimator
-    configurations must share a runner while two different
-    hyperparameter sets must never collide.  Anything unhashable
-    (arrays, live objects) still keys by identity.
+    * primitives, tuples and string-keyed dicts by value, recursively;
+    * *hashable* frozen dataclasses by value — the Workload layer
+      (``core.mlalgos.api``) captures the estimator instance in a
+      default arg, and two equal estimator configurations must share a
+      runner while two different hyperparameter sets must never
+      collide;
+    * captured Python functions by their own signature, recursively
+      (at most :data:`_SIGNATURE_DEPTH` levels, then by identity), so a
+      wrapper rebuilt around an equal function (the minibatch sampler's,
+      the streaming window's) keys like the first one.
+
+    Anything else (arrays, live objects) keys by identity: a step
+    function that captures an array never shares a runner with one
+    that captured another array.  The engine's own step functions
+    therefore capture no arrays: the fit's array constants are a
+    runtime operand of the runner (:func:`bind_consts`).  Callers must
+    keep the closure alive while the key is in use (the cache stores
+    the functions next to the runner) so ``id()`` keys cannot be
+    recycled.
     """
     code = getattr(fn, "__code__", None)
     if code is None:
@@ -212,6 +225,8 @@ def fn_signature(fn) -> tuple:
             except TypeError:
                 return id(v)
             return v
+        if isinstance(v, types.FunctionType) and _depth < _SIGNATURE_DEPTH:
+            return ("fn", fn_signature(v, _depth + 1))
         return id(v)
 
     cells = ()
@@ -225,10 +240,29 @@ def fn_signature(fn) -> tuple:
     return (code, cells, defaults, kwdefaults)
 
 
+def bind_consts(fn: Callable, consts: Any) -> Callable:
+    """The two-argument view ``fn(a, b)`` of a step function that takes
+    the fit's array constants as a third argument.
+
+    Step functions read their array-valued constants (quantization
+    scales) from an operand instead of capturing them, so the runner
+    that scans them is keyed by value and reused across fits with new
+    constants: a runner takes ``consts`` as an argument and binds it
+    here at trace time.  ``consts=None`` means ``fn`` takes two
+    arguments and is returned as it is.  Bound outside a trace, the
+    result captures the arrays and keys by their identity, like any
+    closure over arrays.
+    """
+    if consts is None:
+        return fn
+    return lambda a, b: fn(a, b, consts)
+
+
 def cache_get(grid, key):
     """LRU lookup in the grid's runner cache.  The touch matters:
-    never-repeating keys (quantized paths capture fresh scale arrays per
-    call) must not push the long-lived hot runners out of the window."""
+    keys that never repeat (step functions that capture arrays key by
+    their identity) must not push the long-lived hot runners out of
+    the window."""
     entry = grid._fit_cache.get(key)
     if entry is None:
         return None
@@ -504,7 +538,8 @@ def hop_size(grid) -> int:
 
 
 def wire_spec(grid, local_fn: Callable, update_fn: Callable,
-              state: Any, data: Any, *, merge_every: int = 1):
+              state: Any, data: Any, *, merge_every: int = 1,
+              consts: Any = None):
     """ShapeDtypeStruct tree of what crosses the host hop per merge
     round: the partial-statistics tree at cadence 1, the state tree at
     cadence ``k > 1`` (metrics merge eagerly/exactly and are not part
@@ -514,7 +549,7 @@ def wire_spec(grid, local_fn: Callable, update_fn: Callable,
         sl = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(tuple(x.shape)[1:], x.dtype),
             data)
-        return jax.eval_shape(local_fn, state, sl)
+        return jax.eval_shape(bind_consts(local_fn, consts), state, sl)
     return jax.eval_shape(lambda s: s, state)
 
 
@@ -544,7 +579,7 @@ def _ef_spec(grid):
 
 
 def cadence_round(grid, local_fn: Callable, update_fn: Callable,
-                  k: int, state: Any, data: Any):
+                  k: int, state: Any, data: Any, consts: Any = None):
     """One exact merge round at cadence ``k``: every vDPU runs ``k``
     local update steps on its own copy of ``state`` (no cross-shard
     traffic), then the per-vDPU states and per-step metrics are
@@ -559,16 +594,20 @@ def cadence_round(grid, local_fn: Callable, update_fn: Callable,
     Returns ``(avg_state, metrics)`` with metric leaves of shape
     ``(k, ...)`` — one entry per local step, averaged over vDPUs.
     This is the bit-exact default-plan body; the plan runners above
-    reuse its math through ``pipeline_fns``.
+    reuse its math through ``pipeline_fns``.  ``consts`` is the step
+    functions' array operand (:func:`bind_consts`); on a mesh it enters
+    ``shard_map`` replicated, like ``state``.
     """
     scale = float(grid.n_vdpus)
 
-    def lanes(state, data):
+    def lanes(state, data, consts):
+        lf = bind_consts(local_fn, consts)
+        uf = bind_consts(update_fn, consts)
+
         def per_vdpu(sl):
             def local_step(st, _):
-                part = jax.tree.map(lambda x: x * scale,
-                                    local_fn(st, sl))
-                return update_fn(st, part)
+                part = jax.tree.map(lambda x: x * scale, lf(st, sl))
+                return uf(st, part)
             return jax.lax.scan(local_step, state, None, length=k)
 
         states, metrics = jax.vmap(per_vdpu)(data)
@@ -576,12 +615,12 @@ def cadence_round(grid, local_fn: Callable, update_fn: Callable,
                             (states, metrics))
 
     if grid.mesh is None:
-        states, metrics = lanes(state, data)
+        states, metrics = lanes(state, data, consts)
     else:
         axes = tuple(grid.data_axes)
 
-        def shard_body(state, data):
-            part = lanes(state, data)
+        def shard_body(state, consts, data):
+            part = lanes(state, data, consts)
             for ax in reversed(axes[1:]):
                 part = jax.tree.map(
                     lambda x, a=ax: jax.lax.psum(x, a), part)
@@ -591,8 +630,8 @@ def cadence_round(grid, local_fn: Callable, update_fn: Callable,
         data_specs = jax.tree.map(lambda _: P(axes), data)
         states, metrics = shard_map(
             shard_body, mesh=grid.mesh,
-            in_specs=(P(), data_specs), out_specs=P(),
-            check_vma=False)(state, data)
+            in_specs=(P(), P(), data_specs), out_specs=P(),
+            check_vma=False)(state, consts, data)
 
     inv = 1.0 / scale
     return (jax.tree.map(lambda x: x * inv, states),
@@ -827,7 +866,9 @@ def pipeline_runners(grid, local_fn: Callable, update_fn: Callable, *,
 
     Carries are ``(state, ef, mom)`` / ``(state, pending, ef, mom)``;
     ``mom`` is ``()`` for plain commits, so the extra slot costs
-    nothing there.
+    nothing there.  Each piece takes the step functions' array operand
+    as a trailing ``consts`` argument (:func:`bind_consts`; ``None``
+    for two-argument step functions).
     """
     from repro.kernels import dispatch as _dispatch
 
@@ -838,13 +879,17 @@ def pipeline_runners(grid, local_fn: Callable, update_fn: Callable, *,
     if cached is not None:
         return cached
 
-    merge_fn, compute_fn, commit_fn, prologue = pipeline_fns(
-        grid, local_fn, update_fn, merge_every=merge_every,
-        compression=compression, state_wire=state_wire, outer=outer)
+    def fns(consts):
+        return pipeline_fns(
+            grid, bind_consts(local_fn, consts),
+            bind_consts(update_fn, consts), merge_every=merge_every,
+            compression=compression, state_wire=state_wire, outer=outer)
+
     donate = (0,) if donating_backend() else ()
 
     if overlap:
-        def body_of(data):
+        def body_of(data, consts):
+            merge_fn, compute_fn, commit_fn, _ = fns(consts)
             return double_buffered_body(
                 lambda p, e: merge_fn(p, e),
                 lambda st: compute_fn(st, data),
@@ -852,20 +897,21 @@ def pipeline_runners(grid, local_fn: Callable, update_fn: Callable, *,
 
         @partial(jax.jit, static_argnames=("length",),
                  donate_argnums=donate)
-        def runner(carry, data, *, length: int):
-            return jax.lax.scan(body_of(data), carry, None,
+        def runner(carry, data, consts=None, *, length: int):
+            return jax.lax.scan(body_of(data, consts), carry, None,
                                 length=length)
 
         @jax.jit
-        def round_fn(carry, data):
-            return body_of(data)(carry, None)
+        def round_fn(carry, data, consts=None):
+            return body_of(data, consts)(carry, None)
 
         @jax.jit
-        def prologue_fn(state, data):
-            return prologue(state, data)[0]
+        def prologue_fn(state, data, consts=None):
+            return fns(consts)[3](state, data)[0]
 
         @jax.jit
-        def drain_fn(carry):
+        def drain_fn(carry, consts=None):
+            merge_fn, _, commit_fn, _ = fns(consts)
             state, pending, ef, mom = carry
             merged, ef = merge_fn(pending, ef)
             new_state, mom, _ = commit_fn(state, merged, mom)
@@ -874,7 +920,9 @@ def pipeline_runners(grid, local_fn: Callable, update_fn: Callable, *,
         runners = {"runner": runner, "round": round_fn,
                    "prologue": prologue_fn, "drain": drain_fn}
     else:
-        def body_of(data):
+        def body_of(data, consts):
+            merge_fn, compute_fn, commit_fn, _ = fns(consts)
+
             def body(carry, _):
                 state, ef, mom = carry
                 fresh, compute_metrics = compute_fn(state, data)
@@ -889,13 +937,13 @@ def pipeline_runners(grid, local_fn: Callable, update_fn: Callable, *,
 
         @partial(jax.jit, static_argnames=("length",),
                  donate_argnums=donate)
-        def runner(carry, data, *, length: int):
-            return jax.lax.scan(body_of(data), carry, None,
+        def runner(carry, data, consts=None, *, length: int):
+            return jax.lax.scan(body_of(data, consts), carry, None,
                                 length=length)
 
         @jax.jit
-        def round_fn(carry, data):
-            return body_of(data)(carry, None)
+        def round_fn(carry, data, consts=None):
+            return body_of(data, consts)(carry, None)
 
         runners = {"runner": runner, "round": round_fn}
 
@@ -923,13 +971,17 @@ def _delta_sq_norm(a, b):
 
 
 def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
-            data, steps, callback, scan_chunk, engine, merge_state):
+            data, steps, callback, scan_chunk, engine, merge_state,
+            consts=None):
     """``fit`` driver for every non-default plan (overlap, compression,
     SlowMo, adaptive cadence, auto).  Mirrors ``PimGrid.fit``'s
     contract: returns ``(state, history)`` with one entry per local
     step; reads and writes the ``merge_state`` holder (``"error"``,
     ``"momentum"``, and — for controller-driven plans —
-    ``"cadence_trace"`` / ``"tuning_trace"``)."""
+    ``"cadence_trace"`` / ``"tuning_trace"``).  ``consts`` is the step
+    functions' array operand (:func:`bind_consts`), passed to every
+    runner call; ``tuning.controller.run_controlled_fit`` gets the
+    functions with it bound in."""
     state = init_state
     history: list = []
     if steps > 0 and donating_backend():
@@ -962,7 +1014,7 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
                 wire_cadence = 2 if (plan.adaptive or plan.auto) \
                     else plan.cadence
                 wire = wire_spec(grid, local_fn, update_fn, state, data,
-                                 merge_every=wire_cadence)
+                                 merge_every=wire_cadence, consts=consts)
                 ef = init_merge_error(grid, wire)
             # plan.auto without pinned compression: the controlled-fit
             # driver allocates the shared state-shaped buffer itself
@@ -985,9 +1037,10 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
         from repro.tuning.controller import run_controlled_fit
 
         state, history, ef, ctl = run_controlled_fit(
-            grid, plan, state=state, ef=ef, local_fn=local_fn,
-            update_fn=update_fn, data=data, steps=steps,
-            callback=callback)
+            grid, plan, state=state, ef=ef,
+            local_fn=bind_consts(local_fn, consts),
+            update_fn=bind_consts(update_fn, consts), data=data,
+            steps=steps, callback=callback)
         if merge_state is not None:
             if ef is not None:
                 merge_state["error"] = ef
@@ -1011,19 +1064,19 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
             grid, local_fn, update_fn, merge_every=1, overlap=overlap,
             compression=compression, state_wire=False, outer=outer)
         if overlap:
-            carry = (state, rs["prologue"](state, data), ef, mom) \
-                if steps > 0 else (state, None, ef, mom)
+            carry = (state, rs["prologue"](state, data, consts), ef,
+                     mom) if steps > 0 else (state, None, ef, mom)
         else:
             carry = (state, ef, mom)
         if engine == "python":
             for _ in range(steps):
-                carry, metrics = rs["round"](carry, data)
+                carry, metrics = rs["round"](carry, data, consts)
                 emit(metrics, carry[0])
         else:
             remaining = steps
             while remaining > 0:
                 length = min(scan_chunk, remaining)
-                carry, stacked = rs["runner"](carry, data,
+                carry, stacked = rs["runner"](carry, data, consts,
                                               length=length)
                 for i in range(length):
                     emit(jax.tree.map(lambda x, i=i: x[i], stacked),
@@ -1044,12 +1097,13 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
                 overlap=overlap, compression=compression,
                 state_wire=True, outer=outer)
             if overlap:
-                carry = (state, rs["prologue"](state, data), ef, mom)
+                carry = (state, rs["prologue"](state, data, consts), ef,
+                         mom)
             else:
                 carry = (state, ef, mom)
             if engine == "python":
                 for _ in range(rounds):
-                    carry, stacked = rs["round"](carry, data)
+                    carry, stacked = rs["round"](carry, data, consts)
                     for j in range(merge_every):
                         emit(jax.tree.map(
                             lambda x, j=j: x[j], stacked), carry[0])
@@ -1057,7 +1111,7 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
                 done_rounds = 0
                 while done_rounds < rounds:
                     length = min(scan_chunk, rounds - done_rounds)
-                    carry, stacked = rs["runner"](carry, data,
+                    carry, stacked = rs["runner"](carry, data, consts,
                                                   length=length)
                     for r in range(length):
                         for j in range(merge_every):
@@ -1068,7 +1122,7 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
             if overlap:
                 # drain: the last phase's states are still pending —
                 # commit their delta so no round's work is dropped
-                state, ef, mom = rs["drain"](carry)
+                state, ef, mom = rs["drain"](carry, consts)
             else:
                 state, ef, mom = carry
         if rem:
@@ -1080,7 +1134,7 @@ def run_fit(grid, plan: MergePlan, *, init_state, local_fn, update_fn,
                 overlap=False, compression=compression,
                 state_wire=True, outer=outer)
             (state, ef, mom), stacked = rs_rem["runner"](
-                (state, ef, mom), data, length=1)
+                (state, ef, mom), data, consts, length=1)
             for j in range(rem):
                 emit(jax.tree.map(lambda x, j=j: x[0, j], stacked),
                      state)

@@ -91,8 +91,8 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
     per = rows // n_vdpus
 
     wl = _workload(workload)
-    local_fn, update_fn, state0 = wl.spec_fns(features=features,
-                                              rows=rows)
+    local_fn, update_fn, state0, consts = wl.spec_fns(features=features,
+                                                      rows=rows)
     if batch_size:
         local_fn, update_fn, state0, _ = mb.minibatch_fns(
             local_fn, update_fn, state0, rows_per_vdpu=per,
@@ -107,10 +107,10 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
         "w": jax.ShapeDtypeStruct((n_vdpus, per), jnp.float32,
                                   sharding=grid.data_sharding()),
     }
-    w_spec = jax.tree.map(
+    w_spec, consts_spec = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(tuple(x.shape), x.dtype,
                                        sharding=grid.replicated_sharding()),
-        state0)
+        (state0, consts))
 
     from repro.distributed import merge_plan as mp
     from repro.distributed.compression import CompressionConfig
@@ -137,7 +137,9 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
         from repro import tuning
 
         preset = tuning.AutoTune()
-        model = tuning.CostModel.for_fit(grid, local_fn, update_fn,
+        model = tuning.CostModel.for_fit(grid,
+                                         mp.bind_consts(local_fn, consts),
+                                         mp.bind_consts(update_fn, consts),
                                          w_spec, data_spec)
         # the candidate grid is (wire format x overlap); the table
         # enumerates the unique wires against both overlap settings
@@ -187,7 +189,8 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
         # fit hot path dispatches, scanning `chunk` merge rounds
         runner = grid.make_runner(local_fn, update_fn,
                                   merge_every=merge_every)
-        lowered = runner.lower(w_spec, data_spec, length=chunk)
+        lowered = runner.lower(w_spec, data_spec, consts_spec,
+                               length=chunk)
         return lowered, lowered.compile(), mesh, extra
 
     # plan modes: lower the composed runner on its own carry layout —
@@ -200,7 +203,7 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
                              state_wire=state_wire, outer=outer)
     runner = rs["runner"]
     wire = mp.wire_spec(grid, local_fn, update_fn, w_spec, data_spec,
-                        merge_every=merge_every)
+                        merge_every=merge_every, consts=consts_spec)
     lanes_sharding = grid.data_sharding()
     pending_spec = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct((n_vdpus,) + tuple(s.shape),
@@ -228,7 +231,7 @@ def build(multi_pod: bool, n_vdpus: int = 4096, rows: int = 1 << 24,
         carry = (w_spec, pending_spec, ef_spec, mom_spec)
     else:
         carry = (w_spec, ef_spec, mom_spec)
-    lowered = runner.lower(carry, data_spec, length=chunk)
+    lowered = runner.lower(carry, data_spec, consts_spec, length=chunk)
     return lowered, lowered.compile(), mesh, extra
 
 

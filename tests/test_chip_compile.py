@@ -178,7 +178,7 @@ def test_mesh_logreg_runner(topo, no_persistent_cache, monkeypatch):
     mesh = Mesh(np.asarray(topo.devices).reshape(1, chips), ("pod", "data"),
                 axis_types=(AxisType.Auto,) * 2)
     grid = make_mesh_grid(VDPUS * chips, mesh=mesh)
-    local_fn, update_fn, state0 = LogReg(
+    local_fn, update_fn, state0, consts = LogReg(
         precision="int8", sigmoid="lut").spec_fns(
             features=FEATURES, rows=grid.n_vdpus * GD_ROWS)
     rows = grid.data_sharding()
@@ -187,10 +187,12 @@ def test_mesh_logreg_runner(topo, no_persistent_cache, monkeypatch):
                                       sharding=rows),
             "y0": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows),
             "w": jax.ShapeDtypeStruct(shape, jnp.float32, sharding=rows)}
-    state = jax.ShapeDtypeStruct(state0.shape, state0.dtype,
-                                 sharding=grid.replicated_sharding())
+    state, consts = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=grid.replicated_sharding()),
+        (state0, consts))
     compiled = grid.make_runner(local_fn, update_fn).lower(
-        state, data, length=32).compile()    # api.fit's scan_chunk
+        state, data, consts, length=32).compile()    # api.fit's scan_chunk
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert '"kernel":"fxp_matmul"' in text

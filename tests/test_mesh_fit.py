@@ -20,9 +20,12 @@ fit
   program);
 
 and that the reference with the exchange between chips left out fails
-those limits.
+those limits.  A second mesh fit, on other rows and so with other
+quantization scales, finds its runner in the grid's cache and still
+equals the one-device grid's fit.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -70,22 +73,35 @@ def _child(case: str) -> dict:
     assert len(devices) == cfg["chips"] == DEVICES, devices
 
     grid, rows = grid_and_rows(cfg, devices)
-    X, y = algo.generate(cfg, harness.seed_key(SEED), rows)
     kw = algo.fit_kwargs(traffic, SEED)
     est = harness.make_estimator(cfg)
-    mesh = algo.answer(api.fit(est, grid, X, y, **kw))
-    one = algo.answer(api.fit(est, make_cpu_grid(cfg["n_vdpus"]),
-                              jax.device_put(X, devices[0]),
-                              jax.device_put(y, devices[0]), **kw))
+
+    def fits(X, y):
+        """The mesh fit, the one-device fit, and the runners the mesh
+        fit added to the grid's cache."""
+        n = len(grid._fit_cache)
+        mesh = algo.answer(api.fit(est, grid, X, y, **kw))
+        one = algo.answer(api.fit(est, make_cpu_grid(cfg["n_vdpus"]),
+                                  jax.device_put(X, devices[0]),
+                                  jax.device_put(y, devices[0]), **kw))
+        return mesh, one, len(grid._fit_cache) - n
+
+    X, y = algo.generate(cfg, harness.seed_key(SEED), rows)
+    mesh, one, _ = fits(X, y)
+    X2, y2 = algo.generate(cfg, harness.seed_key(SEED + 1), rows)
+    mesh2, one2, builds2 = fits(X2, y2)
     X, y = np.asarray(X), np.asarray(y)
     ref = algo.reference(cfg, traffic, X, y, SEED)
     no_exchange = algo.reference(cfg, traffic, X, y, SEED,
                                  no_exchange=True)
     return {"mesh_vs_one": algo.compare(mesh, one),
             "program": algo.compare(mesh, ref),
-            "no_exchange": algo.compare(no_exchange, ref)}
+            "no_exchange": algo.compare(no_exchange, ref),
+            "second_mesh_vs_one": algo.compare(mesh2, one2),
+            "second_runner_builds": builds2}
 
 
+@functools.cache
 def _run_child(case: str) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={DEVICES}")
@@ -109,6 +125,14 @@ def test_mesh_fit_matches_one_device_and_reference(case):
     assert out["mesh_vs_one"]["loss_gap"] <= MESH_TOL, out
     assert _within(out["program"], limits), out
     assert not _within(out["no_exchange"], limits), out
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_second_mesh_fit_reuses_runner(case):
+    out = _run_child(case)
+    assert out["second_runner_builds"] == 0, out
+    assert out["second_mesh_vs_one"]["w_gap"] <= MESH_TOL, out
+    assert out["second_mesh_vs_one"]["loss_gap"] <= MESH_TOL, out
 
 
 if __name__ == "__main__":

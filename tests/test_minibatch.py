@@ -163,7 +163,8 @@ class TestMinibatchTraining:
         for steps, k in [(24, 4), (25, 4), (10, 1)]:
             state, _ = grid.fit(init_state=s0, local_fn=lf,
                                 update_fn=uf, data=program.data,
-                                steps=steps, merge_every=k)
+                                steps=steps, merge_every=k,
+                                consts=program.array_consts)
             assert float(state[1]) == float(steps)
 
     def test_full_batch_unchanged_and_minibatch_converges(self):

@@ -117,3 +117,24 @@ def test_runner_build_counts_cache_misses(tmp_path):
     assert [b for b in builds if inside(b, same)] == []
     assert len([b for b in builds if inside(b, changed)]) == 1
     assert len(builds) == 1
+
+
+def test_second_int8_minibatch_fit_builds_no_runner(tmp_path, logreg_data):
+    """A second int8 LUT logreg fit with ``batch_size`` binds anew
+    (``pim.prepare`` runs inside it, with new scale arrays) and finds
+    its runner in the cache: no ``pim.runner_build`` inside its
+    ``pim.fit``."""
+    X, y = logreg_data
+    grid = make_cpu_grid(8)
+    est = LogReg(precision="int8", sigmoid="lut")
+
+    def fit():
+        res = api.fit(est, grid, X, y, steps=STEPS, batch_size=16)
+        jax.block_until_ready(res.state)
+
+    fit()                   # builds and caches the runner
+
+    spans = profiled_spans(tmp_path, fit)
+    (root,) = named(spans, "pim.fit")
+    assert [s for s in named(spans, "pim.prepare") if inside(s, root)]
+    assert named(spans, "pim.runner_build") == []

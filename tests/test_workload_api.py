@@ -24,6 +24,37 @@ from repro.runtime import Trainer, TrainerConfig
 
 KEY = jax.random.PRNGKey(0)
 
+# estimator, fit options and data kind of each refit case
+REFIT_CASES = {
+    "logreg-int8-lut": (LogReg(precision="int8", sigmoid="lut"), {},
+                        "binary"),
+    "logreg-int8-lut-b8": (LogReg(precision="int8", sigmoid="lut"),
+                           {"batch_size": 8}, "binary"),
+    "logreg-fp32-lut": (LogReg(sigmoid="lut"), {}, "binary"),
+    "logreg-fp32-lut-b8": (LogReg(sigmoid="lut"), {"batch_size": 8},
+                           "binary"),
+    "linreg-int8": (LinReg(lr=0.05, precision="int8"), {}, "regression"),
+    "svm-int8": (LinearSVM(precision="int8"), {}, "binary"),
+    "multinomial-int8-lut": (MultinomialLogReg(
+        n_classes=3, precision="int8", softmax="lut"), {}, "classes"),
+    "kmeans-int8": (KMeans(k=3, precision="int8"), {}, "blobs"),
+}
+
+
+def _refit_case(case):
+    """``(workload, fit kwargs, X, y)`` of one refit case, 256 x 6."""
+    wl, kw, kind = REFIT_CASES[case]
+    if kind == "regression":
+        X, y, _ = datasets.regression(KEY, 256, 6)
+    elif kind == "classes":
+        X, y = datasets.mixture_classification(KEY, 256, 6, 3)
+    elif kind == "blobs":
+        X, _, _ = datasets.blobs(KEY, 256, 6, 3)
+        y = None
+    else:
+        X, y, _ = datasets.binary_classification(KEY, 256, 6)
+    return wl, kw, X, y
+
 
 class TestProtocolBitExactness:
     """batch_size=None through api.fit must be bit-exact with the
@@ -144,6 +175,46 @@ class TestProgramCaching:
         r1 = api.fit(LinReg(lr=0.1), grid, X, y, steps=30)
         r2 = api.fit(LinReg(lr=0.01), grid, X, y, steps=30)
         assert float(jnp.max(jnp.abs(r1.state - r2.state))) > 1e-6
+
+    @pytest.mark.parametrize("case", sorted(REFIT_CASES))
+    def test_second_fit_adds_no_runner(self, case):
+        """Every ``api.fit`` binds anew (a new ``prepare``, new scale
+        arrays, a new sigmoid lookup, a new minibatch wrapper), and a
+        second fit of an equal estimator on the same grid still finds
+        its runner in the cache."""
+        wl, kw, X, y = _refit_case(case)
+        grid = make_cpu_grid(4)
+        api.fit(wl, grid, X, y, steps=4, **kw)
+        n = len(grid._fit_cache)
+        api.fit(wl, grid, X, y, steps=4, **kw)
+        assert len(grid._fit_cache) == n
+
+    @pytest.mark.parametrize("case", sorted(REFIT_CASES))
+    def test_new_scales_share_runner_bit_exact(self, case):
+        """The quantization scales are a runner operand, not a trace
+        constant: a dataset of the same shape with other scales reuses
+        the runner, and each fit equals the fit on a fresh grid bit for
+        bit."""
+        wl, kw, X, y = _refit_case(case)
+        X2 = X * jnp.linspace(0.5, 3.0, X.shape[1], dtype=X.dtype)
+        grid = make_cpu_grid(4)
+        fits = [api.fit(wl, grid, X, y, steps=4, **kw)]
+        n = len(grid._fit_cache)
+        fits.append(api.fit(wl, grid, X2, y, steps=4, **kw))
+        assert len(grid._fit_cache) == n
+        for res, data in zip(fits, (X, X2)):
+            fresh = api.fit(wl, make_cpu_grid(4), data, y, steps=4, **kw)
+            for a, b in zip(jax.tree.leaves(res.state),
+                            jax.tree.leaves(fresh.state)):
+                np.testing.assert_array_equal(np.asarray(a),
+                                              np.asarray(b))
+            for h, g in zip(res.history, fresh.history):
+                for a, b in zip(jax.tree.leaves(h), jax.tree.leaves(g)):
+                    np.testing.assert_array_equal(np.asarray(a),
+                                                  np.asarray(b))
+        if wl.precision != "fp32":
+            scales = [wl.prepare(grid, d, y)[2]["x_scale"] for d in (X, X2)]
+            assert not np.array_equal(*map(np.asarray, scales))
 
     def test_fit_result_eval(self):
         X, y, _ = datasets.binary_classification(KEY, 400, 6)
@@ -310,14 +381,14 @@ class TestRegistryAndConfig:
                    LinearSVM(precision="int8"),
                    MultinomialLogReg(n_classes=4, precision="int8",
                                      softmax="lut")):
-            lf, uf, s0 = wl.spec_fns(features=8, rows=64)
+            lf, uf, s0, consts = wl.spec_fns(features=8, rows=64)
             sl = {"X": jax.ShapeDtypeStruct((16, 8), jnp.int8),
                   "y0": jax.ShapeDtypeStruct(
                       (16,), jnp.int32 if wl.name == "multinomial"
                       else jnp.float32),
                   "w": jax.ShapeDtypeStruct((16,), jnp.float32)}
-            part = jax.eval_shape(lf, s0, sl)
-            out = jax.eval_shape(uf, s0, part)
+            part = jax.eval_shape(lf, s0, sl, consts)
+            out = jax.eval_shape(uf, s0, part, consts)
             assert jax.tree.map(lambda x: x.shape, out[0]) \
                 == jax.tree.map(lambda x: x.shape,
                                 jax.eval_shape(lambda: s0))

@@ -146,7 +146,8 @@ class Workload:
 
     ``consts`` is the dict ``prepare`` returns next to the resident
     data: the constants the step functions read (row count, feature
-    count, quantization scales).  :meth:`Program.assemble` splits it:
+    count, and the scales of the row format,
+    :mod:`~repro.core.mlalgos.fixed_point`).  :meth:`Program.assemble` splits it:
     array values become the runner's ``consts`` operand, everything
     else is captured in the assembled closures and keys the compile
     cache by value, so it must be hashable (primitives, frozen
@@ -192,9 +193,9 @@ class Workload:
         softmax variant, same quantized dots), without the metric
         reduction.  fp32 configurations are bit-exact with the
         ``*_predict`` helpers ``eval`` calls; quantized configurations
-        run the same fixed-point recipe as ``local_step``'s forward
-        (per-feature dataset quantization, data scale folded into the
-        requantized weight, integer dots on ``fxp_matmul``).
+        put the request rows in the row format
+        (:mod:`~repro.core.mlalgos.fixed_point`, ``request``) and run
+        ``local_step``'s forward through it.
 
         Must be *pad-invariant*: appending zero rows to ``X`` never
         changes the predictions of the real rows (the serving runner

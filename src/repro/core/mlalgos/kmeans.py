@@ -12,8 +12,9 @@ Pallas kernel via `kernels.dispatch.kmeans_partials` (interpret-mode jnp
 emulation off-TPU; `dispatch.use_kernels(False)` flips to the pure-jnp
 reference).
 
-Fixed-point path (insight I1): points stored int16/int8 with a per-feature
-scale; distances computed in int32 off integer Gram terms.
+Fixed-point path (insight I1): points stored int16/int8 in the row
+format of :mod:`~repro.core.mlalgos.fixed_point`, dequantized on the
+stream into the float distance kernel.
 
 Implemented as a :class:`~repro.core.mlalgos.api.Workload` plugin;
 ``train_kmeans`` is a thin wrapper.  ``batch_size=b`` gives minibatch
@@ -25,17 +26,14 @@ the same safe-mean).
 from __future__ import annotations
 
 import dataclasses
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.mlalgos import api
+from repro.core.mlalgos.fixed_point import Precision, row_format
 from repro.core.pim import PimGrid
-from repro.core import quantize as qz
 from repro.kernels import dispatch
-
-Precision = Literal["fp32", "int16", "int8"]
 
 
 @dataclasses.dataclass
@@ -61,15 +59,9 @@ class KMeans(api.Workload):
         init_idx = jax.random.choice(key, n_rows, (self.k,),
                                      replace=False)
         c0 = jnp.asarray(X)[init_idx]
-        if self.precision == "fp32":
-            data, n = grid.shard_rows(X)
-            consts = {"n": n, "_c0": c0}
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            data, n = grid.shard_rows(Xq.values)
-            consts = {"n": n, "_c0": c0, "x_scale": Xq.scale}  # (1,d)
-        return data, n, consts
+        Xv, scales = row_format(self.precision).place(X)
+        data, n = grid.shard_rows(Xv)
+        return data, n, {"n": n, "_c0": c0, **scales}
 
     def stream_consts(self, stream):
         n = stream.n_rows
@@ -78,32 +70,18 @@ class KMeans(api.Workload):
         # same draw as prepare; the stream's random row access stands
         # in for fancy-indexing the resident array
         c0 = jnp.asarray(stream.rows(init_idx))
-        if self.precision == "fp32":
-            return {"n": n, "_c0": c0}
-        bits = {"int16": 16, "int8": 8}[self.precision]
         return {"n": n, "_c0": c0,
-                "x_scale": qz.symmetric_scale(stream.feature_absmax(),
-                                              bits)}
+                **row_format(self.precision).stream_scales(
+                    stream.feature_absmax)}
 
     def stream_transform(self, consts, X_rows, y_rows):
-        # numpy quantization: keeps the Prefetcher worker JAX-free and
-        # stages int8/int16 H2D bytes (see quantize_fixed_scale_np)
-        if self.precision == "fp32":
-            return (X_rows,)
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return (qz.quantize_fixed_scale_np(X_rows, consts["x_scale"],
-                                           bits),)
+        return (row_format(self.precision).stage(consts, X_rows),)
 
     def init_state(self, consts):
         return consts["_c0"]
 
     def local_step(self, consts, centroids, sl):
-        if self.precision == "fp32":
-            xf = sl["X"]
-        else:
-            # Dequantize-on-stream: the resident copy is integer; the
-            # per-feature scale rides in registers (paper's bank layout).
-            xf = sl["X"].astype(jnp.float32) * consts["x_scale"]
+        xf = row_format(self.precision).dequantize(consts, sl["X"])
         sums, counts, sse = dispatch.kmeans_partials(
             xf, centroids, sl["w"])
         return {"sums": sums, "counts": counts, "sse": sse}
@@ -126,15 +104,11 @@ class KMeans(api.Workload):
         """Serving nearest-centroid assignment — bit-exact with the
         :func:`kmeans_assign_points` ``eval`` uses (both delegate to
         ``dispatch.nearest_centroid``).  Quantized configurations mirror
-        ``local_step``'s dequantize-on-stream: the request rows are
-        quantized on the per-feature grid and dequantized before the
-        distance reduction."""
-        X = jnp.asarray(X)
-        if self.precision != "fp32":
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            X = Xq.values.astype(jnp.float32) * Xq.scale
-        return dispatch.nearest_centroid(X, state)
+        ``local_step``'s dequantize-on-stream: the request rows pass
+        through the row format before the distance reduction."""
+        fmt = row_format(self.precision)
+        Xv, scales = fmt.request(jnp.asarray(X))
+        return dispatch.nearest_centroid(fmt.dequantize(scales, Xv), state)
 
 
 def train_kmeans(grid: PimGrid, X: jax.Array, k: int, *,

@@ -3,12 +3,10 @@
 Paper workload #1.  Each DPU computes the partial gradient
 ``g_p = X_pᵀ(X_p w − y_p)`` over its resident rows; the host merges the
 partials and applies the GD step.  Three numeric paths, as in the paper:
-
-  * ``fp32``   — reference float path (what a CPU/GPU would run),
-  * ``int16`` / ``int8`` — hybrid-precision fixed point: the *dataset copy*
-    is quantized once (per-feature scales), the dot products run in
-    integers with int32 accumulation, and only the merged gradient is
-    rescaled to float for the update (paper's "hybrid precision").
+``fp32`` (the reference float path) and the hybrid-precision ``int16`` /
+``int8`` fixed point, whose rows, dots and rescales are
+:mod:`~repro.core.mlalgos.fixed_point`'s.  Beside quantized rows the
+labels ride as 16-bit fixed point too, with one scale (``y_scale``).
 
 Implemented as a :class:`~repro.core.mlalgos.api.Workload` plugin —
 ``train_linreg`` and ``make_linreg_step`` are thin wrappers over the
@@ -19,17 +17,15 @@ minibatching) applies without algorithm-side threading.
 from __future__ import annotations
 
 import dataclasses
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.mlalgos import api
+from repro.core.mlalgos import api, fixed_point as fxp
+from repro.core.mlalgos.fixed_point import Precision, row_format
 from repro.core.pim import PimGrid
-from repro.core import quantize as qz
-from repro.kernels import dispatch
 
-Precision = Literal["fp32", "int16", "int8"]
+_WIDE_LABELS = fxp.FixedRows(16, key="y_scale", axis=None)
 
 
 @dataclasses.dataclass
@@ -37,12 +33,6 @@ class LinRegResult:
     w: jax.Array
     history: list          # per-step dicts: loss
     precision: str
-
-
-def _quantize_dataset(X, y, bits):
-    Xq = qz.quantize_symmetric(X, bits=bits, axis=0)      # per-feature scale
-    yq = qz.quantize_symmetric(y, bits=16)                 # labels wide
-    return Xq, yq
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,78 +45,41 @@ class LinReg(api.Workload):
 
     name = "linreg"
 
+    def _formats(self):
+        """The row format and the label format: labels stay float on
+        fp32 and are 16-bit fixed point beside quantized rows."""
+        fmt = row_format(self.precision)
+        return fmt, fxp.FLOAT if fmt is fxp.FLOAT else _WIDE_LABELS
+
     def prepare(self, grid: PimGrid, X, y=None):
-        d = X.shape[1]
-        if self.precision == "fp32":
-            data, n = grid.shard_rows(X, y)
-            consts = {"n": n, "d": d}
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq, yq = _quantize_dataset(X, y, bits)
-            # Resident copy is the quantized one (paper: banks hold
-            # fixed point).  The scales are trace-time constants.
-            data, n = grid.shard_rows(Xq.values, yq.values)
-            consts = {"n": n, "d": d, "x_scale": Xq.scale,
-                      "y_scale": yq.scale}
-        return data, n, consts
+        fmt, labels = self._formats()
+        Xv, x_scales = fmt.place(X)
+        yv, y_scales = labels.place(y)
+        data, n = grid.shard_rows(Xv, yv)
+        return data, n, {"n": n, "d": X.shape[1], **x_scales, **y_scales}
 
     def stream_consts(self, stream):
         """Out-of-core constants: the quantized paths derive their
         per-feature / label scales from one-pass host statistics over
         the *whole* stream, so every rotation window quantizes on the
         same grid the resident path would."""
-        n, d = stream.n_rows, stream.n_features
-        if self.precision == "fp32":
-            return {"n": n, "d": d}
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return {"n": n, "d": d,
-                "x_scale": qz.symmetric_scale(stream.feature_absmax(),
-                                              bits),
-                "y_scale": qz.symmetric_scale(stream.label_absmax(), 16)}
+        fmt, labels = self._formats()
+        return {"n": stream.n_rows, "d": stream.n_features,
+                **fmt.stream_scales(stream.feature_absmax),
+                **labels.stream_scales(stream.label_absmax)}
 
     def stream_transform(self, consts, X_rows, y_rows):
-        # numpy mirror of quantize_fixed_scale: this runs on the
-        # Prefetcher worker thread, which must stay JAX-free (a JAX
-        # dispatch there serializes behind the compiled scan) — and the
-        # staged window ships int8/int16 bytes over H2D, not float32
-        if self.precision == "fp32":
-            return X_rows, y_rows
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return (qz.quantize_fixed_scale_np(X_rows, consts["x_scale"],
-                                           bits),
-                qz.quantize_fixed_scale_np(y_rows, consts["y_scale"],
-                                           16))
+        fmt, labels = self._formats()
+        return fmt.stage(consts, X_rows), labels.stage(consts, y_rows)
 
     def init_state(self, consts):
         return jnp.zeros((consts["d"],), jnp.float32)
 
     def local_step(self, consts, w, sl):
-        if self.precision == "fp32":
-            r = (sl["X"] @ w - sl["y0"]) * sl["w"]          # mask padding
-            g = sl["X"].T @ r
-            loss = jnp.sum(r * r)
-            return {"g": g, "loss": loss}
-        # The weight vector is (re)quantized each step inside the local
-        # step, so the resident data stays integer-only and every
-        # multiply is narrow with int32 accumulation (the paper's hybrid
-        # precision).  The per-feature data scale is folded INTO the
-        # weight before quantizing
-        # (pred_r = Σ_k Xq[r,k]·s_k·w_k = Σ_k Xq[r,k]·(s·w)q[k]),
-        # so the forward dot stays purely integer.
-        x_scale = consts["x_scale"]   # (1, d) broadcast against features
-        wq = qz.quantize_symmetric(w * x_scale[0], bits=16)
-        Xi = sl["X"]
-        # (R,d)i @ (d,1)i -> (R,) — int8-limb dots on the fxp_matmul
-        # Pallas kernel, int32 accumulate
-        acc = dispatch.hybrid_matmul(Xi, wq.values[:, None])[:, 0]
-        pred = acc * wq.scale
-        yf = sl["y0"].astype(jnp.float32) * consts["y_scale"]
-        r = (pred - yf) * sl["w"]
-        # gradient: g_k = s_k · Σ_r Xq[r,k]·rq[r] — per-feature scale
-        # factors out per output element, so the fixup is rank-1.
-        rq = qz.quantize_symmetric(r, bits=16)
-        gacc = dispatch.hybrid_matmul(Xi.T, rq.values[:, None])[:, 0]
-        g = gacc * (x_scale[0] * rq.scale)
+        fmt, labels = self._formats()
+        r = (fmt.matmul(consts, sl["X"], w)
+             - labels.dequantize(consts, sl["y0"])) * sl["w"]  # mask padding
+        g = fmt.rmatmul(consts, sl["X"], r)
         return {"g": g, "loss": jnp.sum(r * r)}
 
     def update(self, consts, w, merged):
@@ -143,20 +96,13 @@ class LinReg(api.Workload):
         return out
 
     def predict(self, state, X):
-        """Serving forward pass.  fp32 is bit-exact with the
-        :func:`linreg_predict` ``eval`` uses; the quantized paths run
-        ``local_step``'s forward recipe (per-feature dataset scales,
-        data scale folded into the 16-bit requantized weight, integer
-        dot on ``fxp_matmul``).  Pad-invariant: zero rows never move a
-        per-feature absmax."""
-        X = jnp.asarray(X)
-        if self.precision == "fp32":
-            return linreg_predict(state, X)
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-        wq = qz.quantize_symmetric(state * Xq.scale[0], bits=16)
-        acc = dispatch.hybrid_matmul(Xq.values, wq.values[:, None])[:, 0]
-        return acc * wq.scale
+        """Serving forward pass: ``local_step``'s forward on the
+        request rows in the row format.  fp32 is bit-exact with the
+        :func:`linreg_predict` ``eval`` uses.  Pad-invariant: zero rows
+        never move a per-feature absmax."""
+        fmt = row_format(self.precision)
+        Xv, scales = fmt.request(jnp.asarray(X))
+        return fmt.matmul(scales, Xv, state)
 
 
 def make_linreg_step(grid: PimGrid, X: jax.Array, y: jax.Array, *,

@@ -9,8 +9,9 @@ the lookup table wins:
   * ``lut``    — nearest/interp LUT (the paper's winning variant),
   * ``taylor`` — truncated series (the paper's losing baseline).
 
-Combined with the fixed-point path this reproduces the paper's accuracy
-parity table for logistic regression.  Implemented as a
+Combined with the fixed-point rows of
+:mod:`~repro.core.mlalgos.fixed_point` this reproduces the paper's
+accuracy parity table for logistic regression.  Implemented as a
 :class:`~repro.core.mlalgos.api.Workload` plugin; ``train_logreg`` is a
 thin wrapper over the protocol.
 """
@@ -25,13 +26,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.mlalgos import api
+from repro.core.mlalgos.fixed_point import Precision, row_format
 from repro.core.pim import PimGrid
-from repro.core import quantize as qz
 from repro.core import lut as lut_mod
 from repro.kernels import dispatch
 
 Sigmoid = Literal["exact", "lut", "lut_interp", "taylor"]
-Precision = Literal["fp32", "int16", "int8"]
 
 
 @dataclasses.dataclass
@@ -82,59 +82,29 @@ class LogReg(api.Workload):
     name = "logreg"
 
     def prepare(self, grid: PimGrid, X, y=None):
-        d = X.shape[1]
         sig = make_sigmoid(self.sigmoid, self.lut_entries)
-        if self.precision == "fp32":
-            data, n = grid.shard_rows(X, y)
-            consts = {"n": n, "d": d, "sig": sig}
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            data, n = grid.shard_rows(Xq.values, y)
-            consts = {"n": n, "d": d, "sig": sig, "x_scale": Xq.scale}
-        return data, n, consts
+        Xv, scales = row_format(self.precision).place(X)
+        data, n = grid.shard_rows(Xv, y)
+        return data, n, {"n": n, "d": X.shape[1], "sig": sig, **scales}
 
     def stream_consts(self, stream):
-        n, d = stream.n_rows, stream.n_features
-        sig = make_sigmoid(self.sigmoid, self.lut_entries)
-        if self.precision == "fp32":
-            return {"n": n, "d": d, "sig": sig}
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return {"n": n, "d": d, "sig": sig,
-                "x_scale": qz.symmetric_scale(stream.feature_absmax(),
-                                              bits)}
+        return {"n": stream.n_rows, "d": stream.n_features,
+                "sig": make_sigmoid(self.sigmoid, self.lut_entries),
+                **row_format(self.precision).stream_scales(
+                    stream.feature_absmax)}
 
     def stream_transform(self, consts, X_rows, y_rows):
-        # numpy quantization: keeps the Prefetcher worker JAX-free and
-        # stages int8/int16 H2D bytes (see quantize_fixed_scale_np)
-        if self.precision == "fp32":
-            return X_rows, y_rows
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return (qz.quantize_fixed_scale_np(X_rows, consts["x_scale"],
-                                           bits), y_rows)
+        return row_format(self.precision).stage(consts, X_rows), y_rows
 
     def init_state(self, consts):
         return jnp.zeros((consts["d"],), jnp.float32)
 
     def local_step(self, consts, w, sl):
-        sig = consts["sig"]
-        if self.precision == "fp32":
-            z = sl["X"] @ w
-            p = sig(z)
-            r = (p - sl["y0"]) * sl["w"]
-            g = sl["X"].T @ r
-        else:
-            # fold the per-feature data scale into the weight (see linreg)
-            x_scale = consts["x_scale"]
-            wq = qz.quantize_symmetric(w * x_scale[0], bits=16)
-            Xi = sl["X"]
-            z = dispatch.hybrid_matmul(Xi, wq.values[:, None])[:, 0] \
-                * wq.scale
-            p = sig(z)
-            r = (p - sl["y0"]) * sl["w"]
-            rq = qz.quantize_symmetric(r, bits=16)
-            gacc = dispatch.hybrid_matmul(Xi.T, rq.values[:, None])[:, 0]
-            g = gacc * (x_scale[0] * rq.scale)
+        fmt = row_format(self.precision)
+        z = fmt.matmul(consts, sl["X"], w)
+        p = consts["sig"](z)
+        r = (p - sl["y0"]) * sl["w"]
+        g = fmt.rmatmul(consts, sl["X"], r)
         # BCE loss with the *exact* log for metric reporting (the paper
         # also reports accuracy computed on the host in float).
         eps = 1e-7
@@ -159,29 +129,22 @@ class LogReg(api.Workload):
         LUT / taylor — the LUT variant routes through the
         ``lut_activation`` Pallas kernel exactly as in training).  The
         ``exact``+fp32 configuration is bit-exact with
-        :func:`logreg_predict`; quantized logits run ``local_step``'s
-        integer forward on ``fxp_matmul``."""
-        X = jnp.asarray(X)
+        :func:`logreg_predict`; the logits are ``local_step``'s forward
+        on the request rows in the row format."""
         sig = make_sigmoid(self.sigmoid, self.lut_entries)
-        if self.precision == "fp32":
-            z = X @ state
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            wq = qz.quantize_symmetric(state * Xq.scale[0], bits=16)
-            z = dispatch.hybrid_matmul(Xq.values, wq.values[:, None])[:, 0] \
-                * wq.scale
-        return sig(z)
+        fmt = row_format(self.precision)
+        Xv, scales = fmt.request(jnp.asarray(X))
+        return sig(fmt.matmul(scales, Xv, state))
 
     def spec_fns(self, *, features: int, rows: int):
         """Spec-level engine fns for lowering without resident data
-        (``launch.dryrun_pim``): unit quantization scales, int8
-        resident dataset, the configured sigmoid.  Returns ``(local_fn,
-        update_fn, state0, consts)``: the functions take ``consts``, the
-        array constants, as their third argument, as in ``api.fit``."""
+        (``launch.dryrun_pim``): the row format's unit scales, the
+        configured sigmoid.  Returns ``(local_fn, update_fn, state0,
+        consts)``: the functions take ``consts``, the array constants,
+        as their third argument, as in ``api.fit``."""
         consts = {"n": rows, "d": features,
                   "sig": make_sigmoid(self.sigmoid, self.lut_entries),
-                  "x_scale": jnp.ones((1, features), jnp.float32)}
+                  **row_format(self.precision).unit_scales(features)}
         program = api.Program.assemble(self, None, None, rows, consts)
         return (program.local_fn, program.update_fn, program.state0,
                 program.array_consts)

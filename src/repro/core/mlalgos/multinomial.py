@@ -13,9 +13,9 @@ evaluates exp through a lookup table (``core.lut.exp_lut``) on the
 ``lut_activation`` Pallas kernel — shifted logits ``z − max(z)`` are
 ≤ 0, so the table is one-sided and endpoint clamping is exact enough
 for training (the sigmoid saturation argument).  The fixed-point path
-runs both dots integer-only on ``fxp_matmul`` with per-feature data
-scales folded into the (re)quantized weight matrix, exactly like the
-binary workloads.
+is the binary workloads' row format
+(:mod:`~repro.core.mlalgos.fixed_point`), its dots taking the
+``(d, C)`` weight matrix.
 """
 
 from __future__ import annotations
@@ -26,14 +26,14 @@ from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.mlalgos import api
+from repro.core.mlalgos.fixed_point import Precision, row_format
 from repro.core.pim import PimGrid
 from repro.core import lut as lut_mod
-from repro.core import quantize as qz
 from repro.kernels import dispatch
 
-Precision = Literal["fp32", "int16", "int8"]
 Softmax = Literal["exact", "lut"]
 
 
@@ -85,64 +85,33 @@ class MultinomialLogReg(api.Workload):
     name = "multinomial"
 
     def prepare(self, grid: PimGrid, X, y=None):
-        d = X.shape[1]
         yi = jnp.asarray(y, jnp.int32)
         sm = make_softmax(self.softmax, self.lut_entries)
-        if self.precision == "fp32":
-            data, n = grid.shard_rows(X, yi)
-            consts = {"n": n, "d": d, "sm": sm}
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            data, n = grid.shard_rows(Xq.values, yi)
-            consts = {"n": n, "d": d, "sm": sm, "x_scale": Xq.scale}
-        return data, n, consts
+        Xv, scales = row_format(self.precision).place(X)
+        data, n = grid.shard_rows(Xv, yi)
+        return data, n, {"n": n, "d": X.shape[1], "sm": sm, **scales}
 
     def stream_consts(self, stream):
-        n, d = stream.n_rows, stream.n_features
-        sm = make_softmax(self.softmax, self.lut_entries)
-        if self.precision == "fp32":
-            return {"n": n, "d": d, "sm": sm}
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return {"n": n, "d": d, "sm": sm,
-                "x_scale": qz.symmetric_scale(stream.feature_absmax(),
-                                              bits)}
+        return {"n": stream.n_rows, "d": stream.n_features,
+                "sm": make_softmax(self.softmax, self.lut_entries),
+                **row_format(self.precision).stream_scales(
+                    stream.feature_absmax)}
 
     def stream_transform(self, consts, X_rows, y_rows):
-        import numpy as np
         yi = np.asarray(y_rows, np.int32)     # same cast as prepare
-        if self.precision == "fp32":
-            return X_rows, yi
-        # numpy quantization: keeps the Prefetcher worker JAX-free and
-        # stages int8/int16 H2D bytes (see quantize_fixed_scale_np)
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return (qz.quantize_fixed_scale_np(X_rows, consts["x_scale"],
-                                           bits), yi)
+        return row_format(self.precision).stage(consts, X_rows), yi
 
     def init_state(self, consts):
         return jnp.zeros((consts["d"], self.n_classes), jnp.float32)
 
     def local_step(self, consts, W, sl):
-        sm = consts["sm"]
+        fmt = row_format(self.precision)
         onehot = jax.nn.one_hot(sl["y0"], self.n_classes,
                                 dtype=jnp.float32)
-        if self.precision == "fp32":
-            Z = sl["X"] @ W                                   # (R, C)
-            P = sm(Z)
-            R = (P - onehot) * sl["w"][:, None]
-            G = sl["X"].T @ R                                 # (d, C)
-        else:
-            # fold the per-feature data scale into the weight matrix
-            # (Z_rc = Σ_k Xq[r,k]·s_k·W[k,c]); both dots stay integer
-            x_scale = consts["x_scale"]
-            Wq = qz.quantize_symmetric(W * x_scale[0][:, None], bits=16)
-            Xi = sl["X"]
-            Z = dispatch.hybrid_matmul(Xi, Wq.values) * Wq.scale
-            P = sm(Z)
-            R = (P - onehot) * sl["w"][:, None]
-            Rq = qz.quantize_symmetric(R, bits=16)
-            Gacc = dispatch.hybrid_matmul(Xi.T, Rq.values)
-            G = Gacc * (x_scale[0][:, None] * Rq.scale)
+        Z = fmt.matmul(consts, sl["X"], W)                    # (R, C)
+        P = consts["sm"](Z)
+        R = (P - onehot) * sl["w"][:, None]
+        G = fmt.rmatmul(consts, sl["X"], R)                   # (d, C)
         # cross-entropy with the exact log-softmax for metric reporting
         # (same convention as binary logreg's exact-log BCE)
         logp = jax.nn.log_softmax(Z, axis=-1)
@@ -164,28 +133,21 @@ class MultinomialLogReg(api.Workload):
         """Serving class probabilities ``(n, C)`` through the configured
         softmax (the ``lut`` variant evaluates exp on the Pallas LUT
         kernel, as in training).  ``exact``+fp32 is bit-exact with
-        :func:`multinomial_predict`; quantized logits run
-        ``local_step``'s integer matmul on ``fxp_matmul``."""
-        X = jnp.asarray(X)
+        :func:`multinomial_predict`; the logits are ``local_step``'s
+        forward on the request rows in the row format."""
         sm = make_softmax(self.softmax, self.lut_entries)
-        if self.precision == "fp32":
-            Z = X @ state
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            Wq = qz.quantize_symmetric(state * Xq.scale[0][:, None],
-                                       bits=16)
-            Z = dispatch.hybrid_matmul(Xq.values, Wq.values) * Wq.scale
-        return sm(Z)
+        fmt = row_format(self.precision)
+        Xv, scales = fmt.request(jnp.asarray(X))
+        return sm(fmt.matmul(scales, Xv, state))
 
     def spec_fns(self, *, features: int, rows: int):
-        """Spec-level engine fns for ``launch.dryrun_pim`` (unit
-        quantization scales; no resident data materialized):
+        """Spec-level engine fns for ``launch.dryrun_pim`` (the row
+        format's unit scales; no resident data materialized):
         ``(local_fn, update_fn, state0, consts)``, as
         ``LogReg.spec_fns``."""
         consts = {"n": rows, "d": features,
                   "sm": make_softmax(self.softmax, self.lut_entries),
-                  "x_scale": jnp.ones((1, features), jnp.float32)}
+                  **row_format(self.precision).unit_scales(features)}
         program = api.Program.assemble(self, None, None, rows, consts)
         return (program.local_fn, program.update_fn, program.state0,
                 program.array_consts)

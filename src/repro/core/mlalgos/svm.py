@@ -14,27 +14,22 @@ Per resident row (label mapped to ±1):
     margin m = y·(x·w),  hinge = max(0, 1 − m)
     subgrad g = −y·x  where m < 1, else 0   (+ L2 on the host)
 
-The fixed-point path is the same hybrid-precision recipe as
-linreg/logreg (insight I1): the resident dataset is quantized once
-per-feature, the forward and gradient dots run integer-only on the
-``fxp_matmul`` Pallas kernel with the data scale folded into the
-(re)quantized weight.
+The fixed-point path is the hybrid-precision row format of
+:mod:`~repro.core.mlalgos.fixed_point` (insight I1), as in
+linreg/logreg.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.mlalgos import api
+from repro.core.mlalgos.fixed_point import Precision, row_format
 from repro.core.pim import PimGrid
-from repro.core import quantize as qz
-from repro.kernels import dispatch
-
-Precision = Literal["fp32", "int16", "int8"]
 
 
 @dataclasses.dataclass
@@ -56,63 +51,32 @@ class LinearSVM(api.Workload):
     name = "svm"
 
     def prepare(self, grid: PimGrid, X, y=None):
-        d = X.shape[1]
         ys = jnp.where(jnp.asarray(y) > 0, 1.0, -1.0).astype(jnp.float32)
-        if self.precision == "fp32":
-            data, n = grid.shard_rows(X, ys)
-            consts = {"n": n, "d": d}
-        else:
-            bits = {"int16": 16, "int8": 8}[self.precision]
-            Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-            data, n = grid.shard_rows(Xq.values, ys)
-            consts = {"n": n, "d": d, "x_scale": Xq.scale}
-        return data, n, consts
+        Xv, scales = row_format(self.precision).place(X)
+        data, n = grid.shard_rows(Xv, ys)
+        return data, n, {"n": n, "d": X.shape[1], **scales}
 
     def stream_consts(self, stream):
-        n, d = stream.n_rows, stream.n_features
-        if self.precision == "fp32":
-            return {"n": n, "d": d}
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return {"n": n, "d": d,
-                "x_scale": qz.symmetric_scale(stream.feature_absmax(),
-                                              bits)}
+        return {"n": stream.n_rows, "d": stream.n_features,
+                **row_format(self.precision).stream_scales(
+                    stream.feature_absmax)}
 
     def stream_transform(self, consts, X_rows, y_rows):
         # same ±1 label map as prepare, applied per window
-        import numpy as np
         ys = np.where(np.asarray(y_rows) > 0, 1.0, -1.0).astype(np.float32)
-        if self.precision == "fp32":
-            return X_rows, ys
-        # numpy quantization: keeps the Prefetcher worker JAX-free and
-        # stages int8/int16 H2D bytes (see quantize_fixed_scale_np)
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        return (qz.quantize_fixed_scale_np(X_rows, consts["x_scale"],
-                                           bits), ys)
+        return row_format(self.precision).stage(consts, X_rows), ys
 
     def init_state(self, consts):
         return jnp.zeros((consts["d"],), jnp.float32)
 
     def local_step(self, consts, w, sl):
+        fmt = row_format(self.precision)
         ys = sl["y0"]
-        if self.precision == "fp32":
-            z = sl["X"] @ w
-            active = (ys * z < 1.0).astype(jnp.float32) * sl["w"]
-            # hinge subgradient: −Σ_active y·x  (an MXU dot, like the
-            # other workloads' gradient contraction)
-            g = sl["X"].T @ (-(ys * active))
-        else:
-            # integer forward/gradient dots on fxp_matmul, data scale
-            # folded into the weight (see linreg)
-            x_scale = consts["x_scale"]
-            wq = qz.quantize_symmetric(w * x_scale[0], bits=16)
-            Xi = sl["X"]
-            z = dispatch.hybrid_matmul(Xi, wq.values[:, None])[:, 0] \
-                * wq.scale
-            active = (ys * z < 1.0).astype(jnp.float32) * sl["w"]
-            r = -(ys * active)
-            rq = qz.quantize_symmetric(r, bits=16)
-            gacc = dispatch.hybrid_matmul(Xi.T, rq.values[:, None])[:, 0]
-            g = gacc * (x_scale[0] * rq.scale)
+        z = fmt.matmul(consts, sl["X"], w)
+        active = (ys * z < 1.0).astype(jnp.float32) * sl["w"]
+        # hinge subgradient: −Σ_active y·x  (an MXU dot, like the other
+        # workloads' gradient contraction)
+        g = fmt.rmatmul(consts, sl["X"], -(ys * active))
         hinge = jnp.maximum(0.0, 1.0 - ys * z) * sl["w"]
         return {"g": g, "loss": jnp.sum(hinge)}
 
@@ -129,25 +93,20 @@ class LinearSVM(api.Workload):
         return out
 
     def predict(self, state, X):
-        """Serving decision values (sign = class).  fp32 is bit-exact
-        with the :func:`svm_predict` ``eval`` uses; quantized margins
-        run ``local_step``'s integer forward on ``fxp_matmul``."""
-        X = jnp.asarray(X)
-        if self.precision == "fp32":
-            return svm_predict(state, X)
-        bits = {"int16": 16, "int8": 8}[self.precision]
-        Xq = qz.quantize_symmetric(X, bits=bits, axis=0)
-        wq = qz.quantize_symmetric(state * Xq.scale[0], bits=16)
-        return dispatch.hybrid_matmul(Xq.values, wq.values[:, None])[:, 0] \
-            * wq.scale
+        """Serving decision values (sign = class): ``local_step``'s
+        forward on the request rows in the row format.  fp32 is
+        bit-exact with the :func:`svm_predict` ``eval`` uses."""
+        fmt = row_format(self.precision)
+        Xv, scales = fmt.request(jnp.asarray(X))
+        return fmt.matmul(scales, Xv, state)
 
     def spec_fns(self, *, features: int, rows: int):
-        """Spec-level engine fns for ``launch.dryrun_pim`` (unit
-        quantization scales; no resident data materialized):
+        """Spec-level engine fns for ``launch.dryrun_pim`` (the row
+        format's unit scales; no resident data materialized):
         ``(local_fn, update_fn, state0, consts)``, as
         ``LogReg.spec_fns``."""
         consts = {"n": rows, "d": features,
-                  "x_scale": jnp.ones((1, features), jnp.float32)}
+                  **row_format(self.precision).unit_scales(features)}
         program = api.Program.assemble(self, None, None, rows, consts)
         return (program.local_fn, program.update_fn, program.state0,
                 program.array_consts)

@@ -258,21 +258,6 @@ def dequantize(q: Quantized, dtype=jnp.float32) -> jax.Array:
 # Hybrid-precision linear algebra (narrow multiply, wide accumulate)
 # ---------------------------------------------------------------------------
 
-def fxp_matmul(a: jax.Array, b: jax.Array,
-               acc_dtype=jnp.int32) -> jax.Array:
-    """Integer matmul with wide accumulation: the paper's hybrid precision.
-
-    ``a``: (..., M, K) int8/int16, ``b``: (K, N) int8/int16 ->
-    (..., M, N) ``acc_dtype``.  On TPU this hits the MXU s8 path via
-    ``preferred_element_type``; the pure-jnp semantics are identical.
-    """
-    return jax.lax.dot_general(
-        a, b,
-        dimension_numbers=(((a.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
-
-
 def int8_limbs(x: jax.Array):
     """``[(weight, limb)]`` decomposition into signed int8 limbs, most
     significant first: ``x == sum(weight * limb)``.
@@ -337,17 +322,6 @@ def hybrid_dot(a: jax.Array, b: jax.Array, *, k_chunk: int = 4096
             term = (wa * wb) * acc
             out = term if out is None else out + term
     return out
-
-
-def quantized_dot(xq: Quantized, wq: Quantized,
-                  acc_dtype=jnp.int32, out_dtype=jnp.float32) -> jax.Array:
-    """(M,K)q @ (K,N)q -> float: integer MXU matmul + scale fixup.
-
-    Scales must be per-tensor or per-row(M)/per-col(N) so the fixup is a
-    rank-1 broadcast (this is what per-channel quantization gives you)."""
-    acc = fxp_matmul(xq.values, wq.values, acc_dtype)
-    return acc.astype(out_dtype) * xq.scale.astype(out_dtype) * \
-        wq.scale.astype(out_dtype)
 
 
 # ---------------------------------------------------------------------------
